@@ -14,6 +14,7 @@ from repro.ckpt import manager as ckpt
 from repro.configs import reduced_config
 from repro.distributed.sharding import Runtime
 from repro.data.tokens import batch_for_step
+from repro.launch import compile_cache
 from repro.models.init import init_params
 from repro.train import loop
 from repro.train.optimizer import adamw_init
@@ -23,6 +24,7 @@ CKPT = "/tmp/repro_elastic_demo"
 
 
 def main():
+    compile_cache.enable()
     shutil.rmtree(CKPT, ignore_errors=True)
     cfg = reduced_config("gemma2-9b")
     rt = Runtime(mesh=None)

@@ -11,9 +11,11 @@ from repro.configs.simgnn_aids import CONFIG as CFG
 from repro.core.simgnn import init_simgnn_params, pair_score, simgnn_loss
 from repro.data.graphs import pair_stream
 from repro.kernels.ops import simgnn_pair_score_kernel
+from repro.launch import compile_cache
 
 
 def main():
+    compile_cache.enable()
     params = init_simgnn_params(jax.random.PRNGKey(0), CFG)
     batch = next(pair_stream(seed=0, batch=8))
     args = [jnp.asarray(batch[k]) for k in

@@ -11,11 +11,13 @@ import jax.numpy as jnp
 
 from repro.configs import reduced_config
 from repro.distributed.sharding import Runtime
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.models.init import init_params
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-9b")
     ap.add_argument("--new", type=int, default=12)

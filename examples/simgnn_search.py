@@ -37,11 +37,13 @@ from repro.core.engine import PATHS
 from repro.core.simgnn import init_simgnn_params
 from repro.data.graphs import query_pairs, search_pairs, zipf_corpus, \
     zipf_query_stream
+from repro.launch import compile_cache
 from repro.serve.batching import simgnn_query_server
 from repro.serve.search import SimilaritySearchServer
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", type=int, default=2000)
     ap.add_argument("--batch", type=int, default=256)

@@ -92,6 +92,20 @@ PACKED_PATHS = ("packed_dense", "packed_sparse")
 #: embedding cache serves stale non-differentiable activations.
 TRAIN_PATHS = ("reference", "packed_dense", "packed_sparse")
 
+#: f32 parity bound of every path: max abs error of post-sigmoid scores
+#: against the dense pure-jnp reference (`tests/test_parity_matrix.py`,
+#: `chip_smoke.py`). The reference and the packed/embedding-cached paths
+#: hold 1e-6; the two bucketed fused-GCN paths re-derive normalization
+#: inside the kernel in a different contraction order and hold 2e-5.
+PARITY_ATOL_F32 = {
+    "reference": 1e-6,
+    "two_kernel": 2e-5,
+    "bucketed_mega": 2e-5,
+    "packed_dense": 1e-6,
+    "packed_sparse": 1e-6,
+    "embedding_cache": 1e-6,
+}
+
 #: Graceful-degradation ladder (DESIGN.md §12): when a path's executor
 #: fails (raises, exhausts resources, or emits non-finite scores on
 #: validated inputs) the call steps down to the next rung — specialized
@@ -344,8 +358,11 @@ class ScoringEngine:
         #: "lenient" (default) quarantines per pair (NaN score), "off"
         #: skips validation (trusted in-process generators, benchmarks).
         self.validation = validation
-        #: False pins every call to its planned path — failures propagate
-        #: (debugging / parity harnesses); True (default) walks the ladder.
+        #: False pins every call to its planned path and turns off the
+        #: embed, head and prefilter retries too — failures propagate
+        #: (debugging / parity harnesses / on-chip bring-up, where a silent
+        #: fallback would hide that no kernel ran); True (default) walks
+        #: the ladder.
         self.degrade = degrade
         self.breaker_threshold = breaker_threshold
         self.breaker_cooldown_s = breaker_cooldown_s
@@ -1051,7 +1068,6 @@ class ScoringEngine:
                     (s, g), _ = jax.lax.scan(micro, zero, xs)
                     return s, g
                 if devices > 1:
-                    from jax.experimental.shard_map import shard_map
                     from jax.sharding import PartitionSpec as P
 
                     from repro.distributed.sharding import TILE_AXIS
@@ -1062,11 +1078,11 @@ class ScoringEngine:
                     def local(params, tgt, *arrays):
                         return jax.lax.psum(scan(params, tgt, *arrays),
                                             TILE_AXIS)
-                    fn = shard_map(
+                    fn = jax.shard_map(
                         local, mesh=self._tile_mesh(devices),
                         in_specs=(P(), P(TILE_AXIS))
                         + (P(TILE_AXIS),) * n_arrays,
-                        out_specs=P(), check_rep=False)
+                        out_specs=P(), check_vma=False)
             self._train_fns[key] = jax.jit(fn)
         return self._train_fns[key]
 
@@ -1374,10 +1390,13 @@ class ScoringEngine:
             # retries once on the pure-jnp reference embedder; if that also
             # fails, ONLY this bucket's graphs are dropped (NaN rows, never
             # cached) — the other buckets and every cache hit still serve.
+            # degrade=False raises instead.
             try:
                 hg = run("embed", embed)
             except Exception:
                 self.counters["errors:embed"] += 1
+                if not self.degrade:
+                    raise
                 try:
                     hg = run("embed_fallback", self._embed_fallback())
                     self.counters["embed_fallbacks"] += 1
@@ -1472,7 +1491,8 @@ class ScoringEngine:
         the entire per-query cost of a warm 1-vs-N search (DESIGN.md §10).
         Runs the fused head kernel (`kernels/simgnn_head.py`) except on
         forced-reference engines, which stay kernel-free. A failing or
-        NaN-emitting head retries once on the jnp reference head; pairs
+        NaN-emitting head retries once on the jnp reference head (unless
+        the engine was built with `degrade=False`, which raises); pairs
         whose *embeddings* are already NaN (dropped embed buckets) score
         NaN without tripping the retry."""
         import jax.numpy as jnp
@@ -1497,6 +1517,8 @@ class ScoringEngine:
             return run("head", self._head())
         except Exception:
             self.counters["errors:head"] += 1
+            if not self.degrade:
+                raise
             return run("head_fallback", self._head_fallback())
 
     def _score_cached(self, pairs, idx: np.ndarray, out: np.ndarray,

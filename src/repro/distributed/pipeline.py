@@ -24,7 +24,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -45,10 +44,10 @@ def gpipe(stage_fn, mesh: Mesh, *, axis: str = "stage",
         pspecs = jax.tree.map(lambda _: P(axis), params_stacked)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(pspecs, P()),            # params sharded, data replicated
             out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         def pipelined(params_local, micro_all):
             sidx = jax.lax.axis_index(axis)
             mb = micro_all.shape[1]

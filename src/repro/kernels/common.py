@@ -2,11 +2,10 @@
 SimGNN layer-loop / block-spec helpers used by all three SimGNN kernels.
 
 All kernels in this package target TPU (pl.pallas_call + BlockSpec VMEM
-tiling). On this CPU-only container they are *validated* with interpret=True,
-which executes the kernel body with jnp semantics. `should_interpret()`
-selects interpret mode automatically off-TPU so the same ops.py wrappers run
-everywhere; on a real TPU fleet the flag resolves to False and Mosaic compiles
-the kernels.
+tiling), where Mosaic compiles them. On the CPU backend they run with
+interpret=True, which executes the kernel body with jnp semantics;
+`should_interpret()` picks the mode from the backend so the same ops.py
+wrappers run in both places, and raises on any other backend.
 
 The `*_block` functions below are the in-VMEM compute bodies shared by
 `fused_gcn.py`, `simgnn_head.py`, and the end-to-end megakernel
@@ -29,6 +28,7 @@ packed scoring paths differentiable end-to-end (`kernels/grad.py`,
 from __future__ import annotations
 
 import functools
+from collections import Counter
 
 import numpy as np
 
@@ -36,26 +36,52 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # renamed across jax versions
-    from jax.experimental.pallas import tpu as pltpu
-    CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-except ImportError:  # pragma: no cover
-    pltpu = None
-    CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
+
+#: Precision of every f32 contraction in the SimGNN kernel bodies. A TPU
+#: matmul at default precision (Mosaic's and XLA's alike) is one MXU pass
+#: that rounds f32 operands to bf16; HIGHEST keeps the full f32 mantissa,
+#: which the f32 parity bounds (`core/engine.PARITY_ATOL_F32`) need and
+#: which makes the one-hot gathers below reproduce their rows bit for bit.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def should_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode exactly on the CPU backend (tests, laptops); Mosaic
+    compiles the kernels on TPU. Any other backend raises: there is no
+    kernel lowering for it, and silently interpreting on an accelerator
+    would hide that no kernel ran."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"no Pallas kernel lowering for backend {backend!r}; "
+                       "the kernels run on TPU (or interpreted on CPU)")
 
 
-def compiler_params(dimension_semantics: tuple[str, ...]):
+#: (kernel name, interpret) -> times a SimGNN kernel was traced in this
+#: process. Tracing precedes every compile (and every compile-cache hit),
+#: so this is the proof `chip_smoke.py` checks that each kernel it drove
+#: was lowered by Mosaic and none ran interpreted.
+TRACED_KERNELS: Counter = Counter()
+
+
+def pallas_call(kernel, *, name: str, interpret: bool, **kwargs):
+    """`pl.pallas_call` under a stable kernel name (the name profiler
+    traces and `TRACED_KERNELS` key on)."""
+    TRACED_KERNELS[(name, bool(interpret))] += 1
+    return pl.pallas_call(kernel, name=name, interpret=interpret, **kwargs)
+
+
+def compiler_params(dimension_semantics: tuple[str, ...], *,
+                    vmem_limit_bytes: int | None = None):
     """Grid dimension semantics for Mosaic ('parallel' dims may be reordered;
-    'arbitrary' dims run sequentially so VMEM scratch carries across steps).
-    Returns None in interpret mode (ignored there)."""
-    if should_interpret() or CompilerParams is None:
-        return None
-    return CompilerParams(dimension_semantics=dimension_semantics)
+    'arbitrary' dims run sequentially so VMEM scratch carries across steps)
+    and, where a kernel's program needs more than Mosaic's default scoped
+    VMEM (16 MiB on v5e), its limit. Interpret mode ignores them."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=vmem_limit_bytes)
 
 
 # ---------------------------------------------------------------- block specs
@@ -101,15 +127,39 @@ def _int_zeros(x: jax.Array) -> np.ndarray:
     return np.zeros(np.shape(x), jax.dtypes.float0)
 
 
+def onehot_block(idx: jax.Array, n: int) -> jax.Array:
+    """One-hot of an index plane along a new minor axis: idx [..., M] int ->
+    [..., M, n] fp32, built from an iota compare so Mosaic can lower it
+    (Mosaic has no general vector gather)."""
+    ids = jax.lax.broadcasted_iota(jnp.int32, idx.shape + (n,), idx.ndim)
+    return (idx.astype(jnp.int32)[..., None] == ids).astype(jnp.float32)
+
+
+def gather_rows_block(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """Per-graph row gather x[g, idx[g, m], :] as a one-hot contraction:
+    x [G, N, F], idx [G, M] int -> [G, M, F] fp32. Exact (each output row
+    sums one product with 1.0 at HIGHEST precision), so it is bit-identical
+    to `take_along_axis` for finite rows."""
+    return jax.lax.dot_general(onehot_block(idx, x.shape[1]),
+                               x.astype(jnp.float32),
+                               (((2,), (1,)), ((0,), (0,))),
+                               precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 @jax.custom_vjp
 def label_gather(w: jax.Array, labels: jax.Array) -> jax.Array:
     """First-layer one-hot elimination as a differentiable gather:
-    `one_hot(labels) @ W == W[labels]` exactly, so the forward pass is a row
-    gather (no [M, n_labels] one-hot ever exists). The custom backward keeps
-    the same discipline: dW = one_hot(labels)^T @ g is ONE MXU-shaped
-    [n_labels, M] x [M, F] contraction instead of autodiff's per-row
-    scatter-add. w [L, F], labels [M] int32 -> [M, F] fp32."""
-    return jnp.take(w.astype(jnp.float32), labels, axis=0)
+    `one_hot(labels) @ W == W[labels]` exactly. The one-hot lives only as
+    an in-VMEM [M, n_labels] compare result contracted at HIGHEST precision
+    (exact: one non-zero product per row), so the [N, n_labels] feature
+    block never crosses HBM. The custom backward keeps the same discipline:
+    dW = one_hot(labels)^T @ g is ONE MXU-shaped [n_labels, M] x [M, F]
+    contraction instead of autodiff's per-row scatter-add.
+    w [L, F], labels [G, N] int32 -> [G*N, F] fp32."""
+    onehot = onehot_block(labels, w.shape[0])               # [G, N, L]
+    return jnp.dot(onehot.reshape(-1, w.shape[0]), w.astype(jnp.float32),
+                   precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 def _label_gather_fwd(w, labels):
@@ -118,11 +168,12 @@ def _label_gather_fwd(w, labels):
 
 def _label_gather_bwd(res, g):
     w, labels = res
-    m = labels.shape[0]
-    l_ids = jax.lax.broadcasted_iota(jnp.int32, (w.shape[0], m), 0)
-    onehot_t = (labels[None, :] == l_ids).astype(jnp.float32)   # [L, M]
+    flat = labels.reshape(-1)
+    l_ids = jax.lax.broadcasted_iota(jnp.int32, (w.shape[0], flat.shape[0]),
+                                     0)
+    onehot_t = (flat[None, :] == l_ids).astype(jnp.float32)     # [L, M]
     dw = jnp.dot(onehot_t, g.astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
+                 precision=HIGHEST, preferred_element_type=jnp.float32)
     return dw.astype(w.dtype), _int_zeros(labels)
 
 
@@ -167,16 +218,17 @@ def gcn_layers_block(adj_norm: jax.Array, h: jax.Array | None,
         if li == 0 and labels is not None:
             # Structural feature sparsity: one-hot first layer as a gather
             # (custom VJP: dW1 is one one-hot contraction, no scatter).
-            hw = label_gather(w, labels.reshape(gb * n))
+            hw = label_gather(w, labels)
         else:
             # Feature Transformation (paper MULT+ACC): one 2D MXU matmul for
             # the whole graph block — (GB*N, Fin) @ (Fin, Fout).
             hw = jnp.dot(h.reshape(gb * n, -1), w.astype(jnp.float32),
-                         preferred_element_type=jnp.float32)
+                         precision=HIGHEST, preferred_element_type=jnp.float32)
         hw = (hw + b.astype(jnp.float32)).reshape(gb, n, -1)
         # Aggregation (paper ACG): one batched contraction [GB,N,N]@[GB,N,F]
         # — a single MXU-shaped op instead of a per-graph unrolled dot loop.
         h = jax.lax.dot_general(adj_norm, hw, (((2,), (1,)), ((0,), (0,))),
+                                precision=HIGHEST,
                                 preferred_element_type=jnp.float32)
         # ReLU + mask: the paper's max(0,.) unit at the ACG output.
         h = jnp.maximum(h, 0.0) * mask[..., None]
@@ -254,11 +306,13 @@ def _overflow_aggregate(ov_snd, ov_rcv, ov_w, hw):
     `overflow_aggregate_block` wrapper)."""
     gb, n, f = hw.shape
     e_ov = ov_snd.shape[-1]
-    gathered = jnp.take_along_axis(hw, ov_snd[..., None], axis=1)  # [GB,Eo,F]
+    gathered = gather_rows_block(hw, ov_snd)                       # [GB,Eo,F]
     msgs = gathered * ov_w[..., None].astype(jnp.float32)
     node_ids = jax.lax.broadcasted_iota(jnp.int32, (gb, n, e_ov), 1)
-    scat = (ov_rcv[:, None, :] == node_ids).astype(jnp.float32)    # [GB,N,Eo]
+    scat = (ov_rcv.astype(jnp.int32)[:, None, :]
+            == node_ids).astype(jnp.float32)                       # [GB,N,Eo]
     return jax.lax.dot_general(scat, msgs, (((2,), (1,)), ((0,), (0,))),
+                               precision=HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
@@ -296,19 +350,19 @@ overflow_aggregate_block.defvjp(_overflow_aggregate_fwd,
 def _csr_aggregate(nbr, nbr_w, ov_snd, ov_rcv, ov_w, hw):
     """Raw packed-CSR aggregation body (no VJP rule — see the public
     `csr_aggregate_block` wrapper)."""
-    gb, n, f = hw.shape
+    n = hw.shape[1]
     d = nbr.shape[-1] // n
-    gathered = jnp.take_along_axis(hw, nbr[..., None], axis=1)   # [GB,N*D,F]
-    msgs = (gathered * nbr_w[..., None].astype(jnp.float32)).reshape(gb, d,
-                                                                     n * f)
-    # Plane reduction as D-1 statically-unrolled adds of contiguous
-    # [GB, N*F] planes: keeps the reduction a pure vector add chain (a
-    # strided axis-reduce defeats vectorization on the interpret path).
-    out = msgs[:, 0]
-    for k in range(1, d):
-        out = out + msgs[:, k]
-    return (out.reshape(gb, n, f)
-            + _overflow_aggregate(ov_snd, ov_rcv, ov_w, hw))
+    # Plane reduction as D-1 statically-unrolled adds: plane k (static lane
+    # slice k*N of the ELL planes) gathers its [GB, N, F] messages by one
+    # exact one-hot contraction, so only one plane's one-hot is live at a
+    # time and the reduction stays a pure vector add chain.
+    out = None
+    for k in range(d):
+        sl = slice(k * n, (k + 1) * n)
+        msgs = (gather_rows_block(hw, nbr[:, sl])
+                * nbr_w[:, sl][..., None].astype(jnp.float32))
+        out = msgs if out is None else out + msgs
+    return out + _overflow_aggregate(ov_snd, ov_rcv, ov_w, hw)
 
 
 @jax.custom_vjp
@@ -436,10 +490,10 @@ def gcn_layers_edge_block(nbr: jax.Array, nbr_w: jax.Array,
         if li == 0 and labels is not None:
             # Structural feature sparsity: one-hot first layer as a gather
             # (custom VJP: dW1 is one one-hot contraction, no scatter).
-            hw = label_gather(w, labels.reshape(gb * n))
+            hw = label_gather(w, labels)
         else:
             hw = jnp.dot(h.reshape(gb * n, -1), w.astype(jnp.float32),
-                         preferred_element_type=jnp.float32)
+                         precision=HIGHEST, preferred_element_type=jnp.float32)
         hw = (hw + b.astype(jnp.float32)).reshape(gb, n, -1)
         # A' is symmetric here by construction (undirected + self loops),
         # so the sym variant's scatter-free transpose-aggregate backward
@@ -455,6 +509,7 @@ def att_pool_block(h: jax.Array, mask: jax.Array,
     n_valid = jnp.maximum(jnp.sum(mask, axis=1, keepdims=True), 1.0)   # [GB,1]
     mean_h = jnp.sum(h * mask[..., None], axis=1) / n_valid            # [GB,F]
     c = jnp.tanh(jnp.dot(mean_h, att_w.astype(jnp.float32),
+                         precision=HIGHEST,
                          preferred_element_type=jnp.float32))          # [GB,F]
     att = jax.nn.sigmoid(jnp.sum(h * c[:, None, :], axis=-1)) * mask   # [GB,N]
     return jnp.sum(att[..., None] * h, axis=1)                         # [GB,F]
@@ -488,15 +543,19 @@ def _seg_att_pool_from_onehot(h, mask, s, att_w):
     counts = jnp.maximum(jnp.sum(s, axis=-1, keepdims=True), 1.0)      # [GB,P,1]
     batched = (((2,), (1,)), ((0,), (0,)))
     mean_h = jax.lax.dot_general(s, h, batched,
+                                 precision=HIGHEST,
                                  preferred_element_type=jnp.float32) / counts
     gb, p, f = mean_h.shape
     c = jnp.tanh(jnp.dot(mean_h.reshape(gb * p, f), att_w.astype(jnp.float32),
+                         precision=HIGHEST,
                          preferred_element_type=jnp.float32)).reshape(gb, p, f)
     # Per-node context = its own segment's c, fetched by one S^T contraction.
     c_node = jax.lax.dot_general(s, c, (((1,), (1,)), ((0,), (0,))),
+                                 precision=HIGHEST,
                                  preferred_element_type=jnp.float32)   # [GB,N,F]
     att = jax.nn.sigmoid(jnp.sum(h * c_node, axis=-1)) * mask          # [GB,N]
     return jax.lax.dot_general(s, att[..., None] * h, batched,
+                               precision=HIGHEST,
                                preferred_element_type=jnp.float32)     # [GB,P,F]
 
 
@@ -548,18 +607,24 @@ def ntn_fcn_block(h1: jax.Array, h2: jax.Array, wt: jax.Array, vt: jax.Array,
     """NTN + FCN on one pair block, all in VMEM: h1/h2 [GB, F] -> [GB, 1]
     sigmoid scores. `wt` is W [K,F,F] pre-reshaped to [F, K*F] and `vt` is
     V [K,2F] transposed, so both contractions are pure matmuls."""
-    gb, f = h1.shape
+    f = h1.shape[-1]
     k = bias.shape[0]
     t = jnp.dot(h1, wt.astype(jnp.float32),
+                precision=HIGHEST,
                 preferred_element_type=jnp.float32)                    # [GB,K*F]
-    bilinear = jnp.sum(t.reshape(gb, k, f) * h2[:, None, :], axis=-1)  # [GB,K]
+    # Slice k of the bilinear form is lane block k of t against h2: K
+    # static lane slices (Mosaic has no [GB, K*F] -> [GB, K, F] cast).
+    bilinear = jnp.concatenate(
+        [jnp.sum(t[:, i * f:(i + 1) * f] * h2, axis=-1, keepdims=True)
+         for i in range(k)], axis=-1)                                  # [GB,K]
     cat = jnp.concatenate([h1, h2], axis=-1)                           # [GB,2F]
     linear = jnp.dot(cat, vt.astype(jnp.float32),
-                     preferred_element_type=jnp.float32)
+                     precision=HIGHEST, preferred_element_type=jnp.float32)
     s = jnp.maximum(bilinear + linear + bias.astype(jnp.float32), 0.0)
     n_fc = len(fcn_wb)
     for i, (w, b) in enumerate(fcn_wb):
         s = jnp.dot(s, w.astype(jnp.float32),
+                    precision=HIGHEST,
                     preferred_element_type=jnp.float32) + b.astype(jnp.float32)
         if i + 1 < n_fc:
             s = jnp.maximum(s, 0.0)

@@ -27,12 +27,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.kernels.common import (compiler_params, flatten_layer_params,
                                   gcn_att_block, leading_block_spec,
-                                  read_layer_refs, replicated_spec,
-                                  should_interpret)
+                                  pallas_call, read_layer_refs,
+                                  replicated_spec, should_interpret)
 
 
 def _kernel(adj_ref, feats_ref, mask_ref, *refs):
@@ -64,8 +63,9 @@ def fused_gcn_att(adj_norm: jax.Array, feats: jax.Array, mask: jax.Array,
     def blk(shape):   # per-graph-block operand
         return leading_block_spec((block_graphs,) + shape)
 
-    return pl.pallas_call(
+    return pallas_call(
         _kernel,
+        name="fused_gcn_att",
         grid=grid,
         in_specs=[blk((n, n)), blk((n, feats.shape[-1])), blk((n,))]
                  + [replicated_spec(a) for a in flat + [att_w]],

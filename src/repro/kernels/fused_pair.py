@@ -29,13 +29,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.kernels.common import (compiler_params, flatten_layer_params,
                                   gcn_att_block, leading_block_spec,
                                   normalize_adjacency_block, ntn_fcn_block,
-                                  read_layer_refs, replicated_spec,
-                                  should_interpret)
+                                  pallas_call, read_layer_refs,
+                                  replicated_spec, should_interpret)
 
 
 def _kernel(n_gcn_layers,
@@ -91,8 +90,9 @@ def fused_pair_score(adj1: jax.Array, feats1: jax.Array, mask1: jax.Array,
         return leading_block_spec((block_pairs,) + shape)
 
     f0 = feats1.shape[-1]
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_kernel, len(gcn_params)),
+        name="fused_pair_score",
         grid=(b // block_pairs,),
         in_specs=[blk((n, n)), blk((n, f0)), blk((n,)),
                   blk((n, n)), blk((n, f0)), blk((n,))]

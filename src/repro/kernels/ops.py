@@ -31,7 +31,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.flash_attn import flash_attention
@@ -205,13 +204,14 @@ def pair_score_packed(params, packed, *, tile_block: int | None = None,
 
 
 def sparse_tile_block(node_budget: int) -> int:
-    """Tiles-per-program policy for the packed-sparse megakernel. The sparse
-    tile's VMEM working set drops the [NB, NB] adjacency and A' blocks
-    entirely (edge lists are ~3·E words, ~3 KB at E=256, vs 16 KB+16 KB of
-    fp32 adjacency at NB=64), leaving activations as the footprint
-    (~35 KB/tile side) — so about twice as many tiles fit the same ~2 MB
-    program budget as `packed_tile_block` allows the dense kernel."""
-    return max(1, min(32, 2048 // max(node_budget, 1)))
+    """Tiles-per-program policy for the packed-sparse megakernel. The
+    tile's inputs are small (edge lists are ~3·E words, ~3 KB at E=256, vs
+    16 KB+16 KB of fp32 adjacency at NB=64), but the in-kernel one-hot
+    gathers dominate the working set: the v5e compile measures ~2.7 MB of
+    scoped VMEM per tile at NB=64, so 8 tiles per program fit the kernel's
+    32 MiB limit (`sparse_pair.SPARSE_VMEM_LIMIT`), scaling down with the
+    node budget."""
+    return max(1, min(8, 512 // max(node_budget, 1)))
 
 
 def packed_edge_budget(node_budget: int, avg_degree: float | None = None) -> int:
@@ -339,7 +339,7 @@ def build_pair_score_packed_sharded(mesh: Mesh, node_budget: int, *,
     `tile_block` defaults to the VMEM policy ceiling; callers pass the
     `sharded_tile_plan` block to balance few tiles over many devices.
 
-    check_rep=False: pallas_call carries no replication rule, and every
+    check_vma=False: pallas_call carries no replication rule, and every
     output element is tile-local anyway."""
     from repro.distributed.sharding import TILE_AXIS
 
@@ -354,9 +354,9 @@ def build_pair_score_packed_sharded(mesh: Mesh, node_budget: int, *,
                                  params["ntn"], params["fcn"],
                                  tile_block=tile_block, interpret=interpret)
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                    in_specs=(P(),) + (P(TILE_AXIS),) * 9,
-                   out_specs=P(TILE_AXIS), check_rep=False)
+                   out_specs=P(TILE_AXIS), check_vma=False)
     return jax.jit(fn), tile_block
 
 
@@ -375,9 +375,9 @@ def build_pair_score_sparse_sharded(mesh: Mesh, node_budget: int, *,
                                  params["ntn"], params["fcn"],
                                  tile_block=tile_block, interpret=interpret)
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                    in_specs=(P(),) + (P(TILE_AXIS),) * 17,
-                   out_specs=P(TILE_AXIS), check_rep=False)
+                   out_specs=P(TILE_AXIS), check_vma=False)
     return jax.jit(fn), tile_block
 
 

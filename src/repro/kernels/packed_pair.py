@@ -28,13 +28,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.kernels.common import (compiler_params, flatten_layer_params,
                                   gcn_layers_block, leading_block_spec,
                                   normalize_adjacency_block, ntn_fcn_block,
-                                  read_layer_refs, replicated_spec,
-                                  segment_att_pool_block, should_interpret)
+                                  pallas_call, read_layer_refs,
+                                  replicated_spec, segment_att_pool_block,
+                                  should_interpret)
 
 
 def _kernel(n_gcn_layers,
@@ -45,7 +45,7 @@ def _kernel(n_gcn_layers,
     watt_ref, wt_ref, vt_ref, ntn_b_ref = refs[:4]
     fcn_refs = refs[4:]
     tb = adj1_ref.shape[0]
-    p = pmask_ref.shape[-1]
+    p = pmask_ref.shape[1]
 
     # Stack lhs/rhs tiles into one [2*TB, ...] block (engine reuse ->
     # batching, DESIGN.md §2): one normalization, GCN stack and Att stage
@@ -65,7 +65,9 @@ def _kernel(n_gcn_layers,
                            hg[tb:].reshape(tb * p, f),
                            wt_ref[...], vt_ref[...], ntn_b_ref[...],
                            read_layer_refs(fcn_refs))      # [TB*P, 1]
-    out_ref[...] = (scores.reshape(tb, p)
+    # Pair slots stay on sublanes ([TB, P, 1] out block): a [TB*P, 1] ->
+    # [TB, P] cast would move them onto lanes, which Mosaic cannot lower.
+    out_ref[...] = (scores.reshape(tb, p, 1)
                     * pmask_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
@@ -97,17 +99,18 @@ def packed_pair_score(adj1: jax.Array, labels1: jax.Array, mask1: jax.Array,
     def blk(shape):
         return leading_block_spec((tile_block,) + shape)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_kernel, len(gcn_params)),
+        name="packed_pair_score",
         grid=(t // tile_block,),
         in_specs=[blk((nb, nb)), blk((nb,)), blk((nb,)), blk((nb,)),
                   blk((nb, nb)), blk((nb,)), blk((nb,)), blk((nb,)),
-                  blk((p,))]
+                  blk((p, 1))]
                  + [replicated_spec(a) for a in weights],
-        out_specs=blk((p,)),
-        out_shape=jax.ShapeDtypeStruct((t, p), mask1.dtype),
+        out_specs=blk((p, 1)),
+        out_shape=jax.ShapeDtypeStruct((t, p, 1), mask1.dtype),
         compiler_params=compiler_params(("parallel",)),
         interpret=interpret,
-    )(adj1, labels1, mask1, seg1, adj2, labels2, mask2, seg2, pair_mask,
-      *weights)
-    return out
+    )(adj1, labels1, mask1, seg1, adj2, labels2, mask2, seg2,
+      pair_mask[..., None], *weights)
+    return out[..., 0]

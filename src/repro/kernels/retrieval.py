@@ -35,7 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import (compiler_params, flatten_layer_params,
+from repro.kernels.common import (HIGHEST, compiler_params,
+                                  flatten_layer_params, pallas_call,
                                   read_layer_refs, replicated_spec,
                                   should_interpret)
 
@@ -98,15 +99,50 @@ def _merge_topm(out_s_ref, out_i_ref, s, *, m: int, block_cols: int,
         out_s_ref[...] = jnp.full(out_s_ref.shape, -jnp.inf, out_s_ref.dtype)
         out_i_ref[...] = jnp.zeros(out_i_ref.shape, out_i_ref.dtype)
 
-    # Merge running top-M with this block's scores. `top_k` keeps the
-    # EARLIEST position on ties, and running entries come from earlier
-    # (lower-index) blocks, so ties resolve to the ascending corpus index —
-    # the same order the exact path's stable sort produces.
-    merged_s = jnp.concatenate([out_s_ref[...], s], axis=1)
-    merged_i = jnp.concatenate([out_i_ref[...], col], axis=1)
-    top_s, pos = jax.lax.top_k(merged_s, m)
+    # Merge the running top-M (positions [0, M)) with this block (positions
+    # M + c) by M rounds of max extraction; Mosaic has no top_k. Round r
+    # takes the largest (score, -position) strictly after round r-1's pick,
+    # so ties resolve to the EARLIEST position — running entries come from
+    # earlier (lower-index) blocks, so that is the ascending corpus index,
+    # the same order the exact path's stable sort produces. Tracking the
+    # last pick instead of a taken-mask also ranks the -inf placeholders
+    # and padding correctly (they tie at -inf and go by position).
+    run_s, run_i = out_s_ref[...], out_i_ref[...]
+    pos_r = jax.lax.broadcasted_iota(jnp.int32, run_s.shape, 1)
+    pos_b = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + m
+    slot = jax.lax.broadcasted_iota(jnp.int32, run_s.shape, 1)
+    none = m + block_cols
+
+    def pick(r, carry):
+        last_s, last_p, top_s, top_i = carry                  # [BQ, 1] x2
+
+        def after(v, pos):
+            return (v < last_s) | ((v == last_s) & (pos > last_p))
+
+        ok_r, ok_b = after(run_s, pos_r), after(s, pos_b)
+        best = jnp.maximum(
+            jnp.max(jnp.where(ok_r, run_s, -jnp.inf), axis=1, keepdims=True),
+            jnp.max(jnp.where(ok_b, s, -jnp.inf), axis=1, keepdims=True))
+        first = jnp.minimum(
+            jnp.min(jnp.where(ok_r & (run_s == best), pos_r, none), axis=1,
+                    keepdims=True),
+            jnp.min(jnp.where(ok_b & (s == best), pos_b, none), axis=1,
+                    keepdims=True))
+        idx = jnp.maximum(
+            jnp.max(jnp.where(pos_r == first, run_i, -1), axis=1,
+                    keepdims=True),
+            jnp.max(jnp.where(pos_b == first, col, -1), axis=1,
+                    keepdims=True))
+        top_s = jnp.where(slot == r, best, top_s)
+        top_i = jnp.where(slot == r, idx, top_i)
+        return best, first, top_s, top_i
+
+    bq = run_s.shape[0]
+    init = (jnp.full((bq, 1), jnp.inf, jnp.float32),
+            jnp.full((bq, 1), -1, jnp.int32), run_s, run_i)
+    _, _, top_s, top_i = jax.lax.fori_loop(0, m, pick, init)
     out_s_ref[...] = top_s
-    out_i_ref[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    out_i_ref[...] = top_i
 
 
 def _topm_kernel(qv_ref, c_ref, out_s_ref, out_i_ref, *, m: int,
@@ -114,40 +150,41 @@ def _topm_kernel(qv_ref, c_ref, out_s_ref, out_i_ref, *, m: int,
     qb = qv_ref[...].astype(jnp.float32)                  # [BQ, F]
     cb = c_ref[...].astype(jnp.float32)                   # [BN, F]
     s = jax.lax.dot_general(qb, cb, (((1,), (1,)), ((), ())),
+                            precision=HIGHEST,
                             preferred_element_type=jnp.float32)
     _merge_topm(out_s_ref, out_i_ref, s, m=m, block_cols=block_cols,
                 n_valid=n_valid)
 
 
 def _topm_ntn_kernel(uq_ref, dq_ref, c_ref, *refs, m: int, block_cols: int,
-                     n_valid: int, ntn_k: int, feat: int):
+                     n_valid: int):
     out_s_ref, out_i_ref = refs[-2], refs[-1]
-    layers = read_layer_refs(refs[:-2])
-    uq = uq_ref[...].astype(jnp.float32)                  # [BQ, K*F]
-    dq = dq_ref[...].astype(jnp.float32)                  # [BQ, K]
+    layers = read_layer_refs(refs[:-2])                   # (W^T, b column)
     cb = c_ref[...].astype(jnp.float32)                   # [BN, F]
-    # Exact NTN activations, query side pre-collapsed: slice k of the
-    # bilinear+linear form is one [BQ, F] x [F, BN] matmul against the
-    # corpus block (K matmuls per tile vs the pairwise head's K*F-wide
-    # contraction PER PAIR — the 1-vs-N structure is the whole saving).
-    acts = []
-    for k in range(ntn_k):
-        a = jax.lax.dot_general(uq[:, k * feat:(k + 1) * feat], cb,
+    rows = []
+    for q in range(uq_ref.shape[0]):
+        # Exact NTN activations, query side pre-collapsed: all K slices of
+        # the bilinear+linear form for query q are ONE [K, F] x [F, BN]
+        # matmul against the corpus block (the pairwise head pays a K*F-wide
+        # contraction PER PAIR — the 1-vs-N structure is the whole saving).
+        # Corpus rows stay on lanes through the whole FCN, so every layer
+        # is a [out, in] x [in, BN] matmul and the logits come out as a row.
+        x = jax.lax.dot_general(uq_ref[q].astype(jnp.float32), cb,
                                 (((1,), (1,)), ((), ())),
+                                precision=HIGHEST,
                                 preferred_element_type=jnp.float32)
-        acts.append(a + dq[:, k][:, None])
-    x = jnp.maximum(jnp.stack(acts, axis=-1), 0.0)        # [BQ, BN, K]
-    # Exact FCN on the activation tile; the pre-sigmoid logit is the
-    # proxy (sigmoid is monotone, so top-M is unchanged by skipping it).
-    for li, (wl, bl) in enumerate(layers):
-        x = jax.lax.dot_general(x, wl.astype(jnp.float32),
-                                (((2,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        x = x + bl.astype(jnp.float32)
-        if li + 1 < len(layers):
-            x = jnp.maximum(x, 0.0)
-    _merge_topm(out_s_ref, out_i_ref, x[..., 0], m=m, block_cols=block_cols,
-                n_valid=n_valid)
+        x = jnp.maximum(x + dq_ref[q].astype(jnp.float32), 0.0)   # [K, BN]
+        # Exact FCN on the activation tile; the pre-sigmoid logit is the
+        # proxy (sigmoid is monotone, so top-M is unchanged by skipping it).
+        for li, (wl, bl) in enumerate(layers):
+            x = jnp.dot(wl.astype(jnp.float32), x,
+                        precision=HIGHEST, preferred_element_type=jnp.float32)
+            x = x + bl.astype(jnp.float32)
+            if li + 1 < len(layers):
+                x = jnp.maximum(x, 0.0)
+        rows.append(x)                                    # [1, BN]
+    _merge_topm(out_s_ref, out_i_ref, jnp.concatenate(rows, axis=0), m=m,
+                block_cols=block_cols, n_valid=n_valid)
 
 
 def _rep2(a) -> pl.BlockSpec:
@@ -175,8 +212,9 @@ def _blocked_topm(qv, corpus, *, m: int, block_cols: int, interpret: bool):
     cp = jnp.pad(corpus.astype(jnp.float32), ((0, npad - n), (0, 0)))
     kern = functools.partial(_topm_kernel, m=m, block_cols=block_cols,
                              n_valid=n)
-    out_s, out_i = pl.pallas_call(
+    out_s, out_i = pallas_call(
         kern,
+        name="blocked_topm",
         grid=(qp // block_q, npad // block_cols),
         in_specs=[pl.BlockSpec((block_q, f), lambda i, j: (i, 0)),
                   pl.BlockSpec((block_cols, f), lambda i, j: (j, 0))],
@@ -199,20 +237,26 @@ def _blocked_topm_ntn(uq, dq, corpus, fcn_flat, *, m: int, block_cols: int,
     q, kf = uq.shape
     k = dq.shape[1]
     n, f = corpus.shape
-    # Smaller query block than the dot kernel: the activation tile is
-    # [BQ, block_cols, K] f32 (8 * 1024 * 16 * 4B = 512 KB at the cap).
+    # Smaller query block than the dot kernel: the kernel unrolls one
+    # [K, block_cols] activation tile per query of the block.
     qp, block_q = _pad_pow2(q, 8)
     npad = -(-n // block_cols) * block_cols
-    uq = jnp.pad(uq.astype(jnp.float32), ((0, qp - q), (0, 0)))
-    dq = jnp.pad(dq.astype(jnp.float32), ((0, qp - q), (0, 0)))
+    # Query operands as [Q, K, F] / [Q, K, 1] so uq_ref[q] is a [K, F]
+    # matmul operand and dq_ref[q] a column (no lane->sublane cast in the
+    # kernel); FCN weights transposed to [out, in] with column biases.
+    uq = jnp.pad(uq.astype(jnp.float32),
+                 ((0, qp - q), (0, 0))).reshape(qp, k, f)
+    dq = jnp.pad(dq.astype(jnp.float32), ((0, qp - q), (0, 0)))[..., None]
     cp = jnp.pad(corpus.astype(jnp.float32), ((0, npad - n), (0, 0)))
+    fcn_flat = tuple(a.T if a.ndim == 2 else a[:, None] for a in fcn_flat)
     kern = functools.partial(_topm_ntn_kernel, m=m, block_cols=block_cols,
-                             n_valid=n, ntn_k=k, feat=f)
-    out_s, out_i = pl.pallas_call(
+                             n_valid=n)
+    out_s, out_i = pallas_call(
         kern,
+        name="blocked_topm_ntn",
         grid=(qp // block_q, npad // block_cols),
-        in_specs=[pl.BlockSpec((block_q, kf), lambda i, j: (i, 0)),
-                  pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
+        in_specs=[pl.BlockSpec((block_q, k, f), lambda i, j: (i, 0, 0)),
+                  pl.BlockSpec((block_q, k, 1), lambda i, j: (i, 0, 0)),
                   pl.BlockSpec((block_cols, f), lambda i, j: (j, 0))]
                  + [_rep2(a) for a in fcn_flat],
         out_specs=[pl.BlockSpec((block_q, m), lambda i, j: (i, 0)),

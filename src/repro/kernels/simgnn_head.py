@@ -16,12 +16,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.kernels.common import (compiler_params, flatten_layer_params,
                                   leading_block_spec, ntn_fcn_block,
-                                  read_layer_refs, replicated_spec,
-                                  should_interpret)
+                                  pallas_call, read_layer_refs,
+                                  replicated_spec, should_interpret)
 
 
 def _kernel(h1_ref, h2_ref, wt_ref, vt_ref, b_ref, *fcn_refs):
@@ -52,8 +51,9 @@ def simgnn_head(hg1: jax.Array, hg2: jax.Array, ntn_params, fcn_params, *,
     def blk(shape):
         return leading_block_spec((block_pairs,) + shape)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         _kernel,
+        name="simgnn_head",
         grid=(b // block_pairs,),
         in_specs=[blk((f,)), blk((f,)), replicated_spec(wt),
                   replicated_spec(vt), replicated_spec(ntn_params["b"])]

@@ -42,13 +42,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.kernels.common import (compiler_params, flatten_layer_params,
                                   gcn_layers_edge_block, leading_block_spec,
-                                  ntn_fcn_block, read_layer_refs,
+                                  ntn_fcn_block, pallas_call, read_layer_refs,
                                   replicated_spec, segment_att_pool_block,
                                   should_interpret)
+
+
+#: Scoped VMEM for one packed-sparse program. The one-hot gathers keep a
+#: tile's working set near 2.7 MB at NB=64 (v5e compile: 21.4 MB at 8
+#: tiles per program), above Mosaic's 16 MiB default; `ops.sparse_tile_block`
+#: sizes programs to fit this limit.
+SPARSE_VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _kernel(n_gcn_layers,
@@ -62,7 +68,7 @@ def _kernel(n_gcn_layers,
     watt_ref, wt_ref, vt_ref, ntn_b_ref = refs[:4]
     fcn_refs = refs[4:]
     tb = mask1_ref.shape[0]
-    p = pmask_ref.shape[-1]
+    p = pmask_ref.shape[1]
 
     # Stack lhs/rhs tiles into one [2*TB, ...] block (engine reuse ->
     # batching, DESIGN.md §2): one GCN stack and Att stage serve both sides.
@@ -86,7 +92,9 @@ def _kernel(n_gcn_layers,
                            hg[tb:].reshape(tb * p, f),
                            wt_ref[...], vt_ref[...], ntn_b_ref[...],
                            read_layer_refs(fcn_refs))        # [TB*P, 1]
-    out_ref[...] = (scores.reshape(tb, p)
+    # Pair slots stay on sublanes ([TB, P, 1] out block): a [TB*P, 1] ->
+    # [TB, P] cast would move them onto lanes, which Mosaic cannot lower.
+    out_ref[...] = (scores.reshape(tb, p, 1)
                     * pmask_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
@@ -127,18 +135,27 @@ def sparse_pair_score(nbr1: jax.Array, nbr_w1: jax.Array,
     def blk(shape):
         return leading_block_spec((tile_block,) + shape)
 
+    def idx(plane):
+        # The narrow int16 index planes of `packed_pair_edges` widen on
+        # device: Mosaic tiles 16-bit rows in pairs of sublanes, which a
+        # tile_block below 16 cannot fill.
+        return plane.astype(jnp.int32)
+
     side = [blk((e,)), blk((e,)), blk((e_ov,)), blk((e_ov,)), blk((e_ov,)),
             blk((nb,)), blk((nb,)), blk((nb,))]
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_kernel, len(gcn_params)),
+        name="sparse_pair_score",
         grid=(t // tile_block,),
-        in_specs=side + side + [blk((p,))]
+        in_specs=side + side + [blk((p, 1))]
                  + [replicated_spec(a) for a in weights],
-        out_specs=blk((p,)),
-        out_shape=jax.ShapeDtypeStruct((t, p), mask1.dtype),
-        compiler_params=compiler_params(("parallel",)),
+        out_specs=blk((p, 1)),
+        out_shape=jax.ShapeDtypeStruct((t, p, 1), mask1.dtype),
+        compiler_params=compiler_params(("parallel",),
+                                        vmem_limit_bytes=SPARSE_VMEM_LIMIT),
         interpret=interpret,
-    )(nbr1, nbr_w1, ov_snd1, ov_rcv1, ov_w1, labels1, mask1, seg1,
-      nbr2, nbr_w2, ov_snd2, ov_rcv2, ov_w2, labels2, mask2, seg2, pair_mask,
-      *weights)
-    return out
+    )(idx(nbr1), nbr_w1, idx(ov_snd1), idx(ov_rcv1), ov_w1,
+      labels1, mask1, seg1,
+      idx(nbr2), nbr_w2, idx(ov_snd2), idx(ov_rcv2), ov_w2,
+      labels2, mask2, seg2, pair_mask[..., None], *weights)
+    return out[..., 0]

@@ -1,20 +1,3 @@
-from repro.distributed.sharding import force_host_device_count
-
-force_host_device_count(512)
-# ^ MUST precede every other import that touches devices: jax locks the count
-#   on first backend init. The helper is a no-op when XLA_FLAGS already names
-#   a count (the in-CI smoke test runs with 8 devices instead).
-
-import argparse        # noqa: E402
-import json            # noqa: E402
-import os              # noqa: E402
-import time            # noqa: E402
-import traceback       # noqa: E402
-
-import jax             # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
 """Multi-pod dry-run driver (assignment spec, MULTI-POD DRY-RUN §3).
 
 For every (architecture x input-shape x mesh) cell:
@@ -24,18 +7,31 @@ For every (architecture x input-shape x mesh) cell:
 
 `--mesh single` = (data=16, model=16) v5e-256 pod;
 `--mesh multi`  = (pod=2, data=16, model=16) 512 chips.
+
+`main()` simulates the 512 host devices before anything touches a backend;
+importing this module starts none.
 """
 
-from repro.configs import SHAPES, get_config, reduced_config, shape_applicable  # noqa: E402
-from repro.distributed.sharding import make_runtime  # noqa: E402
-from repro.launch import specs as S  # noqa: E402
-from repro.launch.hlo_analysis import analyze_hlo  # noqa: E402
-from repro.launch.mesh import make_production_mesh, make_test_mesh  # noqa: E402
-from repro.models import lm  # noqa: E402
-from repro.models.init import abstract_params  # noqa: E402
-from repro.serve.step import build_decode_step, build_prefill_step  # noqa: E402
-from repro.train.optimizer import adamw_init  # noqa: E402
-from repro.train.step import build_train_step  # noqa: E402
+import argparse
+import json
+import os
+import time
+import traceback
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro.configs import SHAPES, get_config, reduced_config, shape_applicable
+from repro.distributed.sharding import make_runtime
+from repro.launch import specs as S
+from repro.launch.hlo_analysis import analyze_hlo
+from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.models import lm
+from repro.models.init import abstract_params
+from repro.serve.step import build_decode_step, build_prefill_step
+from repro.train.optimizer import adamw_init
+from repro.train.step import build_train_step
 
 
 def _rep(mesh):
@@ -245,6 +241,11 @@ def _write(out_dir: str, cell_id: str, rec: dict):
 
 
 def main():
+    from repro.distributed.sharding import force_host_device_count
+
+    # Before first backend use: jax locks the device count on init. A no-op
+    # when XLA_FLAGS already names a count (the CI smoke test uses 8).
+    force_host_device_count(512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=[None, *SHAPES])

@@ -150,6 +150,9 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=1)
     ap.add_argument("--simulate-failure", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     if args.model == "simgnn":
         return train_simgnn(args)
     return train_lm(args)

@@ -111,7 +111,7 @@ class SimilaritySearchServer:
                  shard_rows: int = DEFAULT_SHARD_ROWS,
                  recall_sample_every: int = 0,
                  clock: Callable[[], float] = time.perf_counter,
-                 recorder=None, runtime=None):
+                 recorder=None, runtime=None, degrade: bool = True):
         #: injectable timing source for every per-stage SearchStats timer
         #: (mirrors `CircuitBreaker`/`MicroBatcher`): tests drive
         #: deterministic stage seconds with a fake clock, no sleeps. The
@@ -119,11 +119,13 @@ class SimilaritySearchServer:
         self._clock = clock
         #: a multi-device `distributed.sharding.Runtime` splits the
         #: prefilter scan into per-device corpus spans (DESIGN.md §16).
+        #: degrade=False makes embed, head and prefilter failures raise
+        #: instead of falling back (the engine's flag, §12).
         self.engine = ScoringEngine(params, cfg, path="embedding_cache",
                                     cache_size=cache_size,
                                     embed_with_kernels=embed_with_kernels,
                                     clock=clock, recorder=recorder,
-                                    runtime=runtime)
+                                    runtime=runtime, degrade=degrade)
         self.corpus: list[dict] = []
         self.corpus_emb: np.ndarray | None = None
         self.stats = SearchStats()
@@ -379,7 +381,10 @@ class SimilaritySearchServer:
             # including a single dead span of the sharded scan — must not
             # fail the query: serve it through the exact full scan (query
             # embeds are already cached, so only the head re-runs) and
-            # count the degradation for health()/dashboards.
+            # count the degradation for health()/dashboards. An engine
+            # built with degrade=False raises instead.
+            if not self.engine.degrade:
+                raise
             self.engine.counters["prefilter_degraded"] += nq
             self.stats.prefilter_degraded += nq
             return [self._exact_topk(q, k) for q in queries]

@@ -94,7 +94,8 @@ def test_gpipe_pipeline_parallelism(tmp_path):
     script = r'''
 import jax, jax.numpy as jnp
 from repro.distributed.pipeline import gpipe, stack_stage_params
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 d = 16
 def stage_fn(p, x):
     return x + jnp.tanh(x @ p["w1"]) @ p["w2"]

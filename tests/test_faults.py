@@ -122,6 +122,36 @@ def test_degrade_false_pins_path():
             eng.score(_pairs(4, 8))
 
 
+@pytest.mark.parametrize("site", ["embed", "head", "prefilter"])
+def test_degrade_false_disables_fallbacks(site):
+    """degrade=False also turns off the embed, head and prefilter retries:
+    the first failure raises and no fallback counter moves — a run that
+    must prove its kernels ran (chip_smoke.py) cannot be served silently by
+    the jnp reference instead."""
+    from repro.serve.search import SimilaritySearchServer
+
+    rng = np.random.default_rng(21)
+    corpus = [random_graph(rng, int(n), avg_degree=2.0)
+              for n in rng.integers(5, 17, 24)]
+    query = random_graph(rng, 9, avg_degree=2.0)
+    srv = SimilaritySearchServer(PARAMS, CFG, degrade=False)
+    if site != "embed":
+        srv.index(corpus)
+    with faults.inject(site) as plan:
+        with pytest.raises(faults.FaultError):
+            if site == "embed":
+                srv.index(corpus)
+            elif site == "head":
+                srv.topk(query, k=5)
+            else:
+                srv.topk(query, k=5, mode="two_stage", prefilter_m=8)
+    assert plan.triggered == 1
+    counters = srv.engine.counters
+    assert counters[f"errors:{site}"] == 1
+    assert counters["embed_fallbacks"] == 0
+    assert counters["prefilter_degraded"] == 0
+
+
 @pytest.mark.parametrize("mode", ["oom", "nan"])
 def test_oom_and_nan_modes_degrade(mode):
     """RESOURCE_EXHAUSTED and silently-NaN-ing kernels both count as rung

@@ -51,6 +51,21 @@ def test_fused_gcn_shapes(n_nodes, block_graphs):
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_should_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """Interpret mode is the CPU's alone: TPU compiles with Mosaic, and any
+    other backend raises rather than silently interpreting the kernels."""
+    from repro.kernels.common import should_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=backend):
+            should_interpret()
+    else:
+        assert should_interpret() is interpret
+
+
 def test_fused_gcn_bf16():
     cfg = SimGNNConfig()
     params = init_simgnn_params(jax.random.PRNGKey(0), cfg)

@@ -1,7 +1,7 @@
 """Engine-wide parity matrix: every scoring path × dtype × odd/even batch,
 asserted against ONE dense-reference score vector from a single source of
-truth (this file's `REF` + `ATOL` tables) — replacing the per-file ad-hoc
-comparisons as the parity contract.
+truth (this file's `REF` and the engine's `PARITY_ATOL_F32` table) —
+replacing the per-file ad-hoc comparisons as the parity contract.
 
 f32 bounds: the pure-jnp reference and the packed/embedding-cached kernels
 hold 1e-6 (post-sigmoid scores); the two bucketed fused-GCN paths
@@ -18,21 +18,15 @@ import numpy as np
 import pytest
 
 from repro.core.batching import bucket_pairs
-from repro.core.engine import PATHS, ScoringEngine
+from repro.core.engine import PARITY_ATOL_F32, PATHS, ScoringEngine
 from repro.core.simgnn import SimGNNConfig, init_simgnn_params, pair_score
 from repro.data.graphs import random_graph
 
 CFG = SimGNNConfig()
 
-#: single source of truth for the f32 parity bound of every path.
-ATOL_F32 = {
-    "reference": 1e-6,
-    "two_kernel": 2e-5,
-    "bucketed_mega": 2e-5,
-    "packed_dense": 1e-6,
-    "packed_sparse": 1e-6,
-    "embedding_cache": 1e-6,
-}
+#: single source of truth for the f32 parity bound of every path (kept
+#: with the engine so the on-chip smoke test holds the same bounds).
+ATOL_F32 = PARITY_ATOL_F32
 ATOL_BF16 = 2e-2
 BATCHES = (7, 12)        # odd (pads every block policy) and even
 

@@ -165,3 +165,24 @@ def test_microbatcher_deadline_poll():
     assert mb.deadline_in() == 0.0
     assert mb.poll() == [14]
     assert mb.poll() is None             # drained
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache goes
+    to the fixed `<checkout>/.jax_cache` (never a temp, pid or time path)."""
+    import os
+
+    from repro.launch import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/elsewhere")
+        assert compile_cache.enable() == "/cache/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert compile_cache.enable() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
