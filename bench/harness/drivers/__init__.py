@@ -1,0 +1,2 @@
+"""Driving loops, one module per kind of traffic; a traffic file names
+its driver under `"driver"`."""
