@@ -54,20 +54,56 @@ import json
 import os
 import sys
 import tempfile
+import time
 
 import numpy as np
 
-if __package__ in (None, ""):   # `python benchmarks/replay.py` support
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
-from benchmarks.common import finish_check, time_call
 from repro.configs.simgnn_aids import CONFIG as CFG
 from repro.core.engine import TRAIN_PATHS, ScoringEngine
 from repro.core.profile import TraceRecorder, fit_cost_model, read_profile
 from repro.core.simgnn import init_simgnn_params
 from repro.data.graphs import (pair_stream, random_graph, search_pairs,
                                zipf_corpus, zipf_query_stream)
+
+def time_call(fn, *, repeats: int = 2, reduce: str = "min") -> float:
+    """Wall-clock seconds over `repeats` blocking calls of a zero-arg
+    thunk — the replay harness's measurement primitive (DESIGN.md §15).
+    reduce="min" measures *capability* (scheduler noise only ever adds
+    time); reduce="median" matches the central tendency of per-call trace
+    records, which is what a cost model fitted on them predicts."""
+    import jax
+
+    if reduce not in ("min", "median"):
+        raise ValueError(f"unknown reduce {reduce!r}")
+    times = []
+    for _ in range(max(1, repeats)):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn())
+        times.append(time.perf_counter() - t0)
+    times.sort()
+    return times[0] if reduce == "min" else times[len(times) // 2]
+
+
+def finish_check(records: list, failures: list, *, bench: str,
+                 out: str | None, check: bool):
+    """Gate epilogue: appends a `policy: "check"` record carrying the
+    verdict, writes the `--out` JSON artifact BEFORE exiting (so CI gets
+    the measurements and the exact failure strings even when the gate
+    fails), then applies the `--check` exit-code contract. `failures` is
+    the caller's (possibly empty) list of human-readable reasons."""
+    rec = {"bench": bench, "policy": "check", "checked": bool(check),
+           "ok": not failures, "failures": list(failures)}
+    records.append(rec)
+    print("BENCH " + json.dumps(rec))
+    if out:
+        with open(out, "w") as f:
+            json.dump(records, f, indent=1)
+    if check and failures:
+        print("CHECK FAILED: " + "; ".join(failures))
+        sys.exit(1)
+    if check:
+        print("CHECK OK")
+
 
 #: the auto-dispatchable scoring candidates the replay gate measures —
 #: exactly the candidate set `ScoringEngine._planner_estimates` prices

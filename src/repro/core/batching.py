@@ -21,6 +21,11 @@ dominant *structural* zero populations up front:
 
 Buckets/tiles also give XLA a small, fixed set of shapes to compile (one
 executable per bucket), mirroring the paper's per-layer customization.
+
+Every host<->device copy here goes through `to_device`/`to_host`: one
+`engine.to_device`/`engine.to_host` profiler span per call site (carrying
+its byte count), and the bytes added to the `stats` dict the caller passes
+(`h2d_bytes`, `d2h_bytes`), which is how `ScoringEngine` counts them.
 """
 
 from __future__ import annotations
@@ -30,6 +35,8 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core.profile import span
 
 Array = jax.Array
 
@@ -67,8 +74,42 @@ class EdgeBatch(NamedTuple):
         return self.senders.shape[-1]
 
 
-def pad_graphs(graphs: Sequence[dict], n_labels: int, max_nodes: int) -> GraphBatch:
-    """graphs: list of {"adj": np [n,n], "labels": np [n] int}. Pads to max_nodes."""
+def _add_bytes(stats: dict | None, key: str, n: int) -> None:
+    if stats is not None:
+        stats[key] = stats.get(key, 0) + n
+
+
+def to_device(arrays: Sequence[np.ndarray],
+              stats: dict | None = None) -> list:
+    """Copy host arrays to the device inside one `engine.to_device` span;
+    their bytes add to `stats["h2d_bytes"]`."""
+    nbytes = sum(int(a.nbytes) for a in arrays)
+    with span("to_device", bytes=nbytes):
+        out = [jnp.asarray(a) for a in arrays]
+    _add_bytes(stats, "h2d_bytes", nbytes)
+    return out
+
+
+def to_host(arrays: Sequence, stats: dict | None = None) -> list:
+    """Copy arrays to host numpy inside one `engine.to_host` span; the
+    device arrays' bytes add to `stats["d2h_bytes"]`. Waits for the device
+    values first, in an `engine.wait` span, so that the copy span times the
+    transfer and not the device work still producing them. Host arrays pass
+    through uncounted."""
+    on_device = [a for a in arrays if isinstance(a, jax.Array)]
+    with span("wait"):
+        jax.block_until_ready(on_device)
+    nbytes = sum(int(a.nbytes) for a in on_device)
+    with span("to_host", bytes=nbytes):
+        out = [np.asarray(a) for a in arrays]
+    _add_bytes(stats, "d2h_bytes", nbytes)
+    return out
+
+
+def pad_graphs(graphs: Sequence[dict], n_labels: int, max_nodes: int, *,
+               stats: dict | None = None) -> GraphBatch:
+    """graphs: list of {"adj": np [n,n], "labels": np [n] int}. Pads to
+    max_nodes. `stats` receives the copy's `h2d_bytes`."""
     b = len(graphs)
     feats = np.zeros((b, max_nodes, n_labels), np.float32)
     adj = np.zeros((b, max_nodes, max_nodes), np.float32)
@@ -84,9 +125,7 @@ def pad_graphs(graphs: Sequence[dict], n_labels: int, max_nodes: int) -> GraphBa
         mask[i, :n] = 1.0
         n_nodes[i] = n
         labels[i, :n] = g["labels"]
-    return GraphBatch(jnp.asarray(feats), jnp.asarray(adj),
-                      jnp.asarray(mask), jnp.asarray(n_nodes),
-                      jnp.asarray(labels))
+    return GraphBatch(*to_device((feats, adj, mask, n_nodes, labels), stats))
 
 
 def bucket_for(n: int, buckets: Sequence[int] = DEFAULT_BUCKETS, *,
@@ -107,14 +146,15 @@ def bucket_for(n: int, buckets: Sequence[int] = DEFAULT_BUCKETS, *,
 
 def bucket_pairs(pairs: Sequence[tuple], n_labels: int,
                  buckets: Sequence[int] = DEFAULT_BUCKETS, *,
-                 allow_oversize: bool = False):
+                 allow_oversize: bool = False, stats: dict | None = None):
     """Group graph *pairs* by the bucket of the larger graph.
 
     Returns {bucket_size: (GraphBatch_lhs, GraphBatch_rhs, indices)} where
     `indices` restores the original pair order. One compiled executable per
     bucket (the 'customize per workload' principle, paper Table 2). With
     `allow_oversize`, graphs beyond the largest bucket fall into power-of-two
-    overflow buckets instead of raising.
+    overflow buckets instead of raising. `stats` receives the copies'
+    `h2d_bytes`.
     """
     groups: dict[int, list] = {}
     for idx, (g1, g2) in enumerate(pairs):
@@ -124,8 +164,8 @@ def bucket_pairs(pairs: Sequence[tuple], n_labels: int,
     out = {}
     for b, items in sorted(groups.items()):
         idxs = np.asarray([i for i, _, _ in items], np.int32)
-        lhs = pad_graphs([g for _, g, _ in items], n_labels, b)
-        rhs = pad_graphs([g for _, _, g in items], n_labels, b)
+        lhs = pad_graphs([g for _, g, _ in items], n_labels, b, stats=stats)
+        rhs = pad_graphs([g for _, _, g in items], n_labels, b, stats=stats)
         out[b] = (lhs, rhs, idxs)
     return out
 
@@ -221,9 +261,9 @@ def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
     back in so the compiled [T, E_ov] shape stays put. stats then gains
     the measured nnz / adjacency density per side.
 
-    stats: occupancy / pad-fraction per side plus tile shape — the measured
-    quantities benchmarks/packed.py and benchmarks/sparse.py report per
-    policy.
+    stats: occupancy / pad-fraction per side plus tile shape, and the
+    bytes the packing copied to the device (`h2d_bytes`) and back
+    (`d2h_bytes`).
     """
     sizes = [(g1["adj"].shape[0], g2["adj"].shape[0]) for g1, g2 in pairs]
     for n1, n2 in sizes:
@@ -281,20 +321,20 @@ def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
         "pad_fraction_lhs": 1.0 - real[0] / cells,
         "pad_fraction_rhs": 1.0 - real[1] / cells,
         "mean_pairs_per_tile": len(pairs) / n_tiles,
+        "h2d_bytes": 0, "d2h_bytes": 0,
     }
-    packed = PackedPairBatch(
-        jnp.asarray(adj[0]), jnp.asarray(labels[0]), jnp.asarray(mask[0]),
-        jnp.asarray(seg[0]),
-        jnp.asarray(adj[1]), jnp.asarray(labels[1]), jnp.asarray(mask[1]),
-        jnp.asarray(seg[1]),
-        jnp.asarray(pair_mask), jnp.asarray(pair_index))
+    packed = PackedPairBatch(*to_device(
+        (adj[0], labels[0], mask[0], seg[0],
+         adj[1], labels[1], mask[1], seg[1], pair_mask, pair_index), stats))
     if with_edges:
         edges = packed_pair_edges(packed, edge_budget,
-                                  overflow_budget=overflow_budget)
+                                  overflow_budget=overflow_budget,
+                                  stats=stats)
         packed = packed._replace(edges=edges)
-        nnz = [int(np.asarray(e.edge_mask).sum()) + int(np.asarray(o.edge_mask).sum())
-               for e, o in ((edges.edges1, edges.overflow1),
-                            (edges.edges2, edges.overflow2))]
+        m1, o1, m2, o2 = to_host(
+            (edges.edges1.edge_mask, edges.overflow1.edge_mask,
+             edges.edges2.edge_mask, edges.overflow2.edge_mask), stats)
+        nnz = [int(m1.sum()) + int(o1.sum()), int(m2.sum()) + int(o2.sum())]
         adj_cells = n_tiles * node_budget * node_budget
         stats.update(
             edge_budget=edges.edge_budget,
@@ -308,7 +348,8 @@ def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
 
 def packed_pair_edges(packed: PackedPairBatch,
                       edge_budget: int | None = None,
-                      overflow_budget: int = 8) -> PackedEdges:
+                      overflow_budget: int = 8, *,
+                      stats: dict | None = None) -> PackedEdges:
     """Extract per-tile packed-CSR A' edge lists from a packed tile batch
     (DESIGN.md §9).
 
@@ -322,7 +363,15 @@ def packed_pair_edges(packed: PackedPairBatch,
     overflow list. Budgets are powers of two and
     auto-grow to fit (`edge_budget=None` sizes D to the realized max
     in-degree, leaving the overflow empty). Both sides share one budget.
+    Runs in one `engine.edges` span; `stats` receives the bytes of the
+    normalized adjacency read back (`d2h_bytes`) and of the edge lists
+    copied out (`h2d_bytes`).
     """
+    with span("edges"):
+        return _packed_pair_edges(packed, edge_budget, overflow_budget, stats)
+
+
+def _packed_pair_edges(packed, edge_budget, overflow_budget, stats):
     from repro.core.gcn import normalized_adjacency  # late import, no cycle
 
     nb = packed.node_budget
@@ -336,7 +385,7 @@ def packed_pair_edges(packed: PackedPairBatch,
     # by (tile, receiver) and the in-row rank is a searchsorted subtraction.
     sides = []
     for adj, mask in ((packed.adj1, packed.mask1), (packed.adj2, packed.mask2)):
-        a_norm = np.asarray(normalized_adjacency(adj, mask))
+        a_norm = to_host((normalized_adjacency(adj, mask),), stats)[0]
         t = a_norm.shape[0]
         tiles, rows, cols = np.nonzero(a_norm)
         w = a_norm[tiles, rows, cols].astype(np.float32)
@@ -386,20 +435,22 @@ def packed_pair_edges(packed: PackedPairBatch,
             or_.reshape(-1)[oslot] = rows[~fit]
             ow.reshape(-1)[oslot] = w[~fit]
             om.reshape(-1)[oslot] = 1.0
-        out.append((EdgeBatch(jnp.asarray(cs), jnp.asarray(cr),
-                              jnp.asarray(cw), jnp.asarray(cm)),
-                    EdgeBatch(jnp.asarray(os_), jnp.asarray(or_),
-                              jnp.asarray(ow), jnp.asarray(om))))
+        arrays = to_device((cs, cr, cw, cm, os_, or_, ow, om), stats)
+        out.append((EdgeBatch(*arrays[:4]), EdgeBatch(*arrays[4:])))
     return PackedEdges(out[0][0], out[1][0], out[0][1], out[1][1])
 
 
 def unpack_pair_scores(scores_tp, packed: PackedPairBatch,
-                       n_pairs: int) -> np.ndarray:
-    """Scatter kernel output [T, P] back to original pair order (host-side)."""
-    s = np.asarray(scores_tp, np.float32)
-    live = np.asarray(packed.pair_mask) > 0
+                       n_pairs: int, *, stats: dict | None = None
+                       ) -> np.ndarray:
+    """Scatter kernel output [T, P] back to original pair order (host-side).
+    `stats` receives the bytes read back (`d2h_bytes`)."""
+    s, pair_mask, pair_index = to_host(
+        (scores_tp, packed.pair_mask, packed.pair_index), stats)
+    s = s.astype(np.float32, copy=False)
+    live = pair_mask > 0
     out = np.zeros(n_pairs, np.float32)
-    out[np.asarray(packed.pair_index)[live]] = s[live]
+    out[pair_index[live]] = s[live]
     return out
 
 

@@ -64,6 +64,11 @@ thresholds below remain only as the bit-identical cold-profile fallback.
 `benchmarks/replay.py` re-runs a captured mixed trace and gates
 predicted-vs-measured error, so every future kernel's crossover point is
 regression-tested data rather than folklore.
+
+`score()` names its host stages as profiler spans (`core.profile.span`,
+`engine.<stage>` for each of `profile.STAGES`, one per stage per call on
+every executor), and counts the bytes it copies host -> device and back on
+`counters["h2d_bytes"]`/`counters["d2h_bytes"]` at the same sites.
 """
 
 from __future__ import annotations
@@ -80,7 +85,7 @@ import numpy as np
 from repro.core.cache import EmbeddingCache, graph_fingerprint, graph_key
 from repro.core.health import CircuitBreaker
 from repro.core.profile import (TraceRecorder, cost_key, fit_cost_model,
-                                trace_features)
+                                span, trace_features)
 from repro.core.validate import GraphValidationError, validate_pairs
 
 PATHS = ("reference", "two_kernel", "bucketed_mega", "packed_dense",
@@ -247,8 +252,9 @@ class ScoringEngine:
 
     #: densest stream the edge-centric kernel should take: beyond ~4
     #: neighbors/node the edge list stops being much smaller than the dense
-    #: block and the MXU matmul wins (benchmarks/sparse.py measures the
-    #: crossover; LW-GCN/Accel-GCN report the same degree-bound regime).
+    #: block and the MXU matmul wins (LW-GCN/Accel-GCN report the same
+    #: degree-bound regime; the measured planner, DESIGN.md §15, replaces
+    #: this threshold once it has a profile).
     SPARSE_MAX_DEGREE = 4.0
     #: below this many pairs, FFD packing cannot fill even one tile enough
     #: to beat a single bucketed launch.
@@ -369,8 +375,9 @@ class ScoringEngine:
         self._clock = clock
         #: per-(path, shape-class) circuit breakers, created lazily.
         self.breakers: dict[tuple, CircuitBreaker] = {}
-        #: error/degradation/quarantine counters reported by `health()`.
-        self.counters: Counter = Counter()
+        #: error/degradation/quarantine counters reported by `health()`,
+        #: with the cumulative bytes scoring copied to and from the device.
+        self.counters: Counter = Counter(h2d_bytes=0, d2h_bytes=0)
         #: bucketed callables for non-default flavors — only populated when
         #: degradation crosses flavors (e.g. bucketed_mega -> reference on a
         #: kernel-flavored engine). `bucket_fns` keeps its public int-keyed
@@ -617,18 +624,19 @@ class ScoringEngine:
         occ = (float(pstats.get("occupancy_lhs", 0.0)
                      + pstats.get("occupancy_rhs", 0.0)) / 2.0
                if pstats else 0.0)
-        try:
-            _call("profile", lambda: rec.record(
-                kind=kind, path=path, n_pairs=int(n_pairs),
-                max_nodes=plan.stats.max_nodes,
-                mean_nodes=plan.stats.mean_nodes,
-                avg_degree=plan.stats.avg_degree,
-                density=plan.stats.density, occupancy=occ,
-                to_embed=len(plan.to_embed_idx),
-                degraded_from=list(degraded), attempts=int(attempts),
-                wall_s=float(wall_s), n_devices=int(n_devices)))
-        except Exception:
-            self.counters["profile_record_errors"] += 1
+        with span("record"):
+            try:
+                _call("profile", lambda: rec.record(
+                    kind=kind, path=path, n_pairs=int(n_pairs),
+                    max_nodes=plan.stats.max_nodes,
+                    mean_nodes=plan.stats.mean_nodes,
+                    avg_degree=plan.stats.avg_degree,
+                    density=plan.stats.density, occupancy=occ,
+                    to_embed=len(plan.to_embed_idx),
+                    degraded_from=list(degraded), attempts=int(attempts),
+                    wall_s=float(wall_s), n_devices=int(n_devices)))
+            except Exception:
+                self.counters["profile_record_errors"] += 1
 
     def _graph_keys(self, pairs: Sequence[tuple]) -> tuple:
         """Canonical keys of every graph in the call: all lhs, then all rhs
@@ -669,8 +677,9 @@ class ScoringEngine:
         quarantined: tuple = ()
         valid_idx = np.arange(n, dtype=np.int64)
         if self.validation != "off" and n:
-            valid_idx, quarantined = validate_pairs(
-                pairs, n_labels=self.cfg.n_node_labels)
+            with span("validate"):
+                valid_idx, quarantined = validate_pairs(
+                    pairs, n_labels=self.cfg.n_node_labels)
             if quarantined and self.validation == "strict":
                 raise GraphValidationError(quarantined)
         valid = (pairs if len(valid_idx) == n
@@ -678,8 +687,9 @@ class ScoringEngine:
         # Density only steers the auto sparse/dense split and the sparse
         # edge budget; forced paths that ignore it skip the O(sum n_i^2)
         # adjacency scan.
-        stats = self.workload_stats(
-            valid, measure_density=self.path in ("auto", "packed_sparse"))
+        with span("stats"):
+            stats = self.workload_stats(
+                valid, measure_density=self.path in ("auto", "packed_sparse"))
         # The cache steers dispatch only when it could hold answers: keys
         # are hashed (O(sum n_i), host-side) iff the path is forced to the
         # cached one, or auto sees a non-empty cache — a cold cache costs
@@ -692,41 +702,45 @@ class ScoringEngine:
                 and self.cache.capacity > 0 and (
                 self.path == "embedding_cache"
                 or (self.path == "auto" and len(self.cache))):
-            keys = self._graph_keys(valid)
-            unique = set(keys)
-            hits = sum(1 for k in unique if k in self.cache)
+            with span("graph_keys"):
+                keys = self._graph_keys(valid)
+                unique = set(keys)
+                hits = sum(1 for k in unique if k in self.cache)
             hit_frac = hits / len(unique)
             n_to_embed = len(unique) - hits
-        path, reason, est = self._select(stats, hit_frac, train=train,
-                                         n_to_embed=n_to_embed,
-                                         keys_known=bool(keys))
-        cached_idx = to_embed_idx = np.empty(0, np.int64)
-        if path == "embedding_cache" and keys:
-            hit = [k in self.cache for k in keys]
-            cached_idx = np.flatnonzero(hit)
-            first = {k: i for i, k in reversed(list(enumerate(keys)))}
-            to_embed_idx = np.asarray(
-                sorted(i for k, i in first.items() if not hit[i]), np.int64)
-        if path in PACKED_PATHS:
-            fits = np.asarray([max(g1["adj"].shape[0], g2["adj"].shape[0])
-                               <= self.node_budget for g1, g2 in valid], bool)
-            fit_idx = valid_idx[np.flatnonzero(fits)]
-            over_idx = valid_idx[np.flatnonzero(~fits)]
-        elif path == "embedding_cache":
-            # The embed stage buckets internally with power-of-two overflow,
-            # so nothing is oversized for this path.
-            fit_idx = valid_idx
-            over_idx = np.empty(0, np.int64)
-        else:
-            fit_idx = np.empty(0, np.int64)
-            over_idx = valid_idx
-        fallback = "reference" if train else self._bucket_flavor
-        return ScorePlan(path=path, fallback=fallback,
-                         fit_idx=fit_idx, over_idx=over_idx, stats=stats,
-                         reason=reason, cached_idx=cached_idx,
-                         to_embed_idx=to_embed_idx, graph_keys=keys,
-                         quarantined=quarantined, cost_estimates=est,
-                         devices=self._plan_devices(path, stats))
+        with span("select"):
+            path, reason, est = self._select(stats, hit_frac, train=train,
+                                             n_to_embed=n_to_embed,
+                                             keys_known=bool(keys))
+            cached_idx = to_embed_idx = np.empty(0, np.int64)
+            if path == "embedding_cache" and keys:
+                hit = [k in self.cache for k in keys]
+                cached_idx = np.flatnonzero(hit)
+                first = {k: i for i, k in reversed(list(enumerate(keys)))}
+                to_embed_idx = np.asarray(
+                    sorted(i for k, i in first.items() if not hit[i]),
+                    np.int64)
+            if path in PACKED_PATHS:
+                fits = np.asarray(
+                    [max(g1["adj"].shape[0], g2["adj"].shape[0])
+                     <= self.node_budget for g1, g2 in valid], bool)
+                fit_idx = valid_idx[np.flatnonzero(fits)]
+                over_idx = valid_idx[np.flatnonzero(~fits)]
+            elif path == "embedding_cache":
+                # The embed stage buckets internally with power-of-two
+                # overflow, so nothing is oversized for this path.
+                fit_idx = valid_idx
+                over_idx = np.empty(0, np.int64)
+            else:
+                fit_idx = np.empty(0, np.int64)
+                over_idx = valid_idx
+            fallback = "reference" if train else self._bucket_flavor
+            return ScorePlan(path=path, fallback=fallback,
+                             fit_idx=fit_idx, over_idx=over_idx, stats=stats,
+                             reason=reason, cached_idx=cached_idx,
+                             to_embed_idx=to_embed_idx, graph_keys=keys,
+                             quarantined=quarantined, cost_estimates=est,
+                             devices=self._plan_devices(path, stats))
 
     # ------------------------------------------------------------ execution
 
@@ -762,40 +776,34 @@ class ScoringEngine:
 
     def _score_bucketed(self, pairs, idx: np.ndarray, out: np.ndarray,
                         flavor: str | None = None):
-        from repro.core.batching import bucket_pairs
+        from repro.core.batching import bucket_pairs, to_host
 
         site = flavor or self._bucket_flavor
-        for bucket, (lhs, rhs, idxs) in bucket_pairs(
-                pairs, self.cfg.n_node_labels, allow_oversize=True).items():
+        with span("pack"):
+            buckets = bucket_pairs(pairs, self.cfg.n_node_labels,
+                                   allow_oversize=True, stats=self.counters)
+        for bucket, (lhs, rhs, idxs) in buckets.items():
             fn = self._bucket_fn(bucket, flavor)
-            s = _call(site, lambda fn=fn, lhs=lhs, rhs=rhs: fn(
-                self.params, lhs.adj, lhs.feats, lhs.mask,
-                rhs.adj, rhs.feats, rhs.mask))
-            out[idx[idxs]] = np.asarray(s)
+            with span("kernel"):
+                s = _call(site, lambda fn=fn, lhs=lhs, rhs=rhs: fn(
+                    self.params, lhs.adj, lhs.feats, lhs.mask,
+                    rhs.adj, rhs.feats, rhs.mask))
+            out[idx[idxs]] = to_host((s,), self.counters)[0]
 
     def _score_packed(self, pairs, idx: np.ndarray, out: np.ndarray,
                       sparse: bool, stats: WorkloadStats):
-        from repro.core.batching import pack_pairs, unpack_pair_scores
+        from repro.core.batching import unpack_pair_scores
         from repro.kernels import ops
 
-        # Fixed slots_per_tile + power-of-two tile/edge quantization keep the
-        # compiled-shape set small (O(log T) executables) under varying batch
-        # sizes and FFD outcomes.
-        slots = max(8, self.node_budget // 4)
-        if sparse:
-            packed, pstats = self._pack_sparse(pairs, slots,
-                                               stats.avg_degree)
-            s = _call("packed_sparse",
-                      lambda: ops.pair_score_sparse(self.params, packed,
-                                                    quantize_tiles=True))
-        else:
-            packed, pstats = pack_pairs(pairs, self.node_budget,
-                                        slots_per_tile=slots)
-            s = _call("packed_dense",
-                      lambda: ops.pair_score_packed(self.params, packed,
-                                                    quantize_tiles=True))
+        packed, pstats = self._pack(pairs, sparse, stats)
+        score = ops.pair_score_sparse if sparse else ops.pair_score_packed
+        with span("kernel"):
+            s = _call("packed_sparse" if sparse else "packed_dense",
+                      lambda: score(self.params, packed, quantize_tiles=True))
         self.last_pack_stats = pstats
-        out[idx] = unpack_pair_scores(s, packed, len(pairs))
+        with span("unpack"):
+            out[idx] = unpack_pair_scores(s, packed, len(pairs),
+                                          stats=self.counters)
 
     @staticmethod
     def _packed_score_arrays(packed, sparse: bool) -> tuple:
@@ -824,33 +832,50 @@ class ScoringEngine:
         shard_map executor, gather [T, P] scores host-side. The fault site
         is `sharded:<path>` — a dead shard surfaces here and the §12 ladder
         collapses the call to the single-device rung."""
-        from repro.core.batching import pack_pairs, unpack_pair_scores
+        from repro.core.batching import unpack_pair_scores
         from repro.kernels import ops
 
         path = "packed_sparse" if sparse else "packed_dense"
-        slots = max(8, self.node_budget // 4)
-        if sparse:
-            packed, pstats = self._pack_sparse(pairs, slots, stats.avg_degree)
-        else:
-            packed, pstats = pack_pairs(pairs, self.node_budget,
-                                        slots_per_tile=slots)
+        packed, pstats = self._pack(pairs, sparse, stats)
         t = packed.mask1.shape[0]
         target, tile_block = ops.sharded_tile_plan(
             t, self.node_budget, devices, sparse=sparse)
         fn = self._sharded_fn(path, devices, tile_block)
-        arrays = [ops._pad_batch(x, target)[0]
-                  for x in self._packed_score_arrays(packed, sparse)]
-        s = _call(f"sharded:{path}",
-                  lambda: fn(self.params, *arrays))[:t]
-        span = target // devices
+        with span("kernel"):
+            arrays = [ops._pad_batch(x, target)[0]
+                      for x in self._packed_score_arrays(packed, sparse)]
+            s = _call(f"sharded:{path}",
+                      lambda: fn(self.params, *arrays))[:t]
+        per_device = target // devices
         self.last_pack_stats = dict(
             pstats, devices=devices, tiles=t, tiles_padded=target,
             # live-tile fraction of each device's span (pad tiles append at
             # the end, so trailing devices absorb the padding waste).
             device_occupancy=[
-                max(0, min(t - d * span, span)) / span
+                max(0, min(t - d * per_device, per_device)) / per_device
                 for d in range(devices)])
-        out[idx] = unpack_pair_scores(s, packed, len(pairs))
+        with span("unpack"):
+            out[idx] = unpack_pair_scores(s, packed, len(pairs),
+                                          stats=self.counters)
+
+    def _pack(self, pairs, sparse: bool, stats: WorkloadStats):
+        """Pack a scoring work item into tiles (one `engine.pack` span) and
+        count the bytes packing copied. Fixed slots_per_tile + power-of-two
+        tile/edge quantization keep the compiled-shape set small (O(log T)
+        executables) under varying batch sizes and FFD outcomes."""
+        from repro.core.batching import pack_pairs
+
+        slots = max(8, self.node_budget // 4)
+        with span("pack"):
+            if sparse:
+                packed, pstats = self._pack_sparse(pairs, slots,
+                                                   stats.avg_degree)
+            else:
+                packed, pstats = pack_pairs(pairs, self.node_budget,
+                                            slots_per_tile=slots)
+        self.counters["h2d_bytes"] += pstats["h2d_bytes"]
+        self.counters["d2h_bytes"] += pstats["d2h_bytes"]
+        return packed, pstats
 
     def _pack_sparse(self, pairs, slots: int, avg_degree: float):
         """Shared sparse packing (scoring + training): ladder-sized edge
@@ -977,8 +1002,10 @@ class ScoringEngine:
     def health(self) -> dict:
         """Inspectable fault-tolerance + planner state (DESIGN.md §12/§15):
         breaker snapshots keyed by path and shape class, error/degradation/
-        quarantine counters, the embedding-LRU counters, and the measured
-        planner (profile size, fitted model support + residuals)."""
+        quarantine counters with the bytes scoring copied to and from the
+        device (`h2d_bytes`, `d2h_bytes`), the embedding-LRU counters, and
+        the measured planner (profile size, fitted model support +
+        residuals)."""
         rec = self.recorder
         planner: dict = {"mode": self.planner,
                          "enabled": self._model is not None
@@ -1343,7 +1370,7 @@ class ScoringEngine:
         calls, and inserted. Returns `[len(graphs), F]` float32 in input
         order — duplicates within one call are embedded once.
         """
-        from repro.core.batching import bucket_for, pad_graphs
+        from repro.core.batching import bucket_for, pad_graphs, to_host
 
         f = self.cfg.gcn_dims[-1]
         out = np.zeros((len(graphs), f), np.float32)
@@ -1374,13 +1401,17 @@ class ScoringEngine:
             buckets.setdefault(b, []).append((k, g))
         embed = self._embed_fn()
         for b, items in sorted(buckets.items()):
-            batch = pad_graphs([g for _, g in items],
-                               self.cfg.n_node_labels, b)
+            with span("pack"):
+                batch = pad_graphs([g for _, g in items],
+                                   self.cfg.n_node_labels, b,
+                                   stats=self.counters)
 
             def run(site, fn):
-                h = np.asarray(_call(site, lambda: fn(
-                    self.params, batch.adj, batch.feats, batch.mask)),
-                    np.float32)
+                with span("kernel"):
+                    h = _call(site, lambda: fn(
+                        self.params, batch.adj, batch.feats, batch.mask))
+                h = to_host((h,), self.counters)[0].astype(np.float32,
+                                                         copy=False)
                 if not np.isfinite(h).all():
                     raise NonFiniteOutput(
                         f"{site} produced non-finite embeddings")
@@ -1495,18 +1526,19 @@ class ScoringEngine:
         the engine was built with `degrade=False`, which raises); pairs
         whose *embeddings* are already NaN (dropped embed buckets) score
         NaN without tripping the retry."""
-        import jax.numpy as jnp
+        from repro.core.batching import to_device, to_host
 
         hg1 = np.asarray(hg1, np.float32)
         hg2 = np.asarray(hg2, np.float32)
         row_ok = (np.isfinite(hg1).all(axis=-1)
                   & np.isfinite(hg2).all(axis=-1))
-        h1 = jnp.asarray(hg1)
-        h2 = jnp.asarray(hg2)
+        h1, h2 = to_device((hg1, hg2), self.counters)
 
         def run(site, fn):
-            s = np.asarray(_call(site, lambda: fn(self.params, h1, h2)),
-                           np.float32)
+            with span("kernel"):
+                s = _call(site, lambda: fn(self.params, h1, h2))
+            s = to_host((s,), self.counters)[0].astype(np.float32,
+                                                       copy=False)
             if not np.isfinite(s[row_ok]).all():
                 raise NonFiniteOutput(
                     f"{site} produced non-finite scores for finite "
@@ -1538,8 +1570,13 @@ class ScoringEngine:
         each work item (the planned path's fit split, the fallback's
         oversize split) walks the degradation ladder on executor failure.
         The executed plan — including `degraded_from` and `attempts` — is
-        republished on `last_plan`.
+        republished on `last_plan`. The call runs in one `engine.score`
+        span, its stages in spans of their own (`core.profile.STAGES`).
         """
+        with span("score"):
+            return self._score(pairs)
+
+    def _score(self, pairs: Sequence[tuple]) -> np.ndarray:
         out = np.zeros(len(pairs), np.float32)
         plan = self.plan(pairs)
         self.last_plan = plan

@@ -94,18 +94,6 @@ def gcn_stack_from_labels(layers, adj_norm: Array, labels: Array,
     return h
 
 
-def gcn_stack_unfused_baseline(layers, adj_norm: Array, h: Array, mask: Array) -> Array:
-    """Paper's *baseline* architecture analogue: each layer is its own jit
-    region, so intermediates round-trip through HBM between layers (the
-    FPGA baseline stored intermediates in global memory). Used only by
-    benchmarks/table4.py to reproduce the paper's ablation structure."""
-    step = jax.jit(lambda p, a, x, m: gcn_layer(p, a, x, m, activation=True))
-    for p in layers:
-        h = step(p, adj_norm, h, mask)
-        h = jax.block_until_ready(h)
-    return h
-
-
 def activation_sparsity(h: Array, mask: Array) -> Array:
     """Fraction of exact zeros among real-node activations (paper §3.4 reports
     52%/47% for SimGNN layers 2/3; we measure rather than exploit — DESIGN §2)."""

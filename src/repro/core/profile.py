@@ -31,6 +31,13 @@ Version 1 profiles (no `n_devices` field) are still read — their records
 are facts about single-device runs, so `n_devices=1` — and upgrade to v2
 in place on the next flush.
 
+  * `span` — the host stages of `ScoringEngine.score` as profiler spans
+    (`jax.profiler.TraceAnnotation("engine.<stage>")`, one per stage per
+    call, never per graph or pair). The profiler records them on the same
+    clock as the device's operations, so a trace attributes each stretch
+    of device idle time to the host stage that ran in it. They are always
+    in the code: off the profiler one costs about a microsecond.
+
 `schema_digest()` pins the record schema the way the `graph_key` golden
 hashes pin the WL hash (tests/test_cache.py): a reader either understands
 a persisted profile or refuses it with a structured `ProfileError`
@@ -50,8 +57,37 @@ from dataclasses import asdict, astuple, dataclass
 from typing import Callable, Iterable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.store import StoreError, atomic_write_bytes
+
+#: Host stages of a `ScoringEngine.score` call, each a profiler span named
+#: `"engine." + stage`:
+#:   score       the whole call
+#:   validate    `validate_pairs` (quarantine)
+#:   stats       `workload_stats`
+#:   graph_keys  canonical graph keys and cache residency (warm cache only)
+#:   select      the dispatch decision, cost-model refits and the
+#:               fit/oversize split
+#:   pack        tile packing or size bucketing and the host array fill
+#:   edges       `packed_pair_edges` (packed-sparse path)
+#:   to_device   host -> device copies of one call site; carries `bytes`
+#:   to_host     device -> host copies of one call site; carries `bytes`
+#:   kernel      the call into an executor, up to its return (dispatch)
+#:   wait        blocking on device results before they are copied back
+#:   unpack      `unpack_pair_scores`
+#:   record      the call's `TraceRecord`
+STAGES = ("score", "validate", "stats", "graph_keys", "select", "pack",
+          "edges", "to_device", "to_host", "kernel", "wait", "unpack",
+          "record")
+SPAN_PREFIX = "engine."
+
+
+def span(stage: str, **args) -> TraceAnnotation:
+    """Context manager recording one host stage as the profiler span
+    `engine.<stage>`; keyword `args` become the span's stats (the copy
+    stages carry `bytes`)."""
+    return TraceAnnotation(SPAN_PREFIX + stage, **args)
 
 #: Bump when `TRACE_SCHEMA` changes shape or meaning. Readers refuse any
 #: other version (ProfileError) instead of guessing — a mis-parsed latency

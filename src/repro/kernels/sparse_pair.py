@@ -16,10 +16,9 @@ split:
   * per layer, aggregation costs O(NB·D·F) gathered messages (D = per-node
     neighbor budget from the `ops.packed_edge_budget` ladder — 4 at
     AIDS-like degree ~2.1) instead of O(NB²·F) MACs — ~14x fewer
-    aggregation FLOPs at the default budgets (benchmarks/sparse.py reports
-    the measured ratio); the regular ELLPACK planes reduce with
-    statically-unrolled contiguous adds (no scatter), only the heavy-tail
-    overflow edges take a small one-hot contraction;
+    aggregation FLOPs at the default budgets; the regular ELLPACK planes
+    reduce with statically-unrolled contiguous adds (no scatter), only the
+    heavy-tail overflow edges take a small one-hot contraction;
   * the first layer keeps PR 2's one-hot elimination: int32 labels ride
     into the kernel and the widest H·W becomes a W1 row gather
     (`gcn_layers_edge_block(labels=...)`), so no [N, n_labels] one-hot is

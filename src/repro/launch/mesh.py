@@ -2,8 +2,8 @@
 
 A function, not a module-level constant, so importing this module never
 touches jax device state. Axis roles are documented in
-distributed/sharding.py; hardware constants for the roofline live in
-benchmarks/roofline.py.
+distributed/sharding.py; the chip's peaks live with the benchmark, in
+bench/harness/device.py.
 """
 
 from __future__ import annotations

@@ -15,10 +15,6 @@ OpenCL/PCIe setup (2.8x E2E there). The TPU analogues implemented here:
     plus the oversize fallback split) lives in the engine's `plan()`; the
     wrapper only maps the legacy `use_kernels`/`packing` flags onto an
     engine path and keeps the public score_fn attribute contract.
-
-benchmarks/fig11.py sweeps `max_batch` to reproduce the paper's batching
-curve on this implementation; benchmarks/packed.py and benchmarks/sparse.py
-compare the scoring paths head-to-head.
 """
 
 from __future__ import annotations
@@ -30,7 +26,7 @@ from typing import Callable
 
 @dataclass
 class FlushStats:
-    """Measured MicroBatcher behavior (benchmarks/fig11.py reads these
+    """Measured MicroBatcher behavior (flush counts by trigger, read
     instead of inferring occupancy from the request count)."""
     batches: int = 0               # total flushes that ran a batch
     requests: int = 0              # total requests flushed

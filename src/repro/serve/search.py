@@ -6,8 +6,7 @@ every pair is query-independent, so this server indexes the corpus ONCE
 (GCN+Att embeddings through the engine's cache) and serves each query with
 one query-side embedding plus a batched NTN+FCN head over the whole corpus
 — the head kernel (`kernels/simgnn_head.py`) is the entire per-query device
-cost. `benchmarks/search.py` measures the resulting warm-corpus speedup vs
-rescoring every pair through the packed-sparse path.
+cost.
 
 The server is a thin orchestration layer: all scoring goes through
 `core.engine.ScoringEngine` (`embed_graphs` / `pair_scores_from_embeddings`),

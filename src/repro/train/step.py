@@ -94,8 +94,8 @@ def build_simgnn_apply(*, peak_lr: float = 1e-3,
                        max_grad_norm: float = 1.0):
     """The jitted SimGNN optimizer half-step (clip -> cosine schedule ->
     AdamW), shared by `build_simgnn_train_step` and any baseline that must
-    pair a different loss with the SAME update (benchmarks/train.py's
-    dense-reference policy) — one source for the schedule constants."""
+    pair a different loss with the SAME update — one source for the
+    schedule constants."""
     @jax.jit
     def apply(params, opt_state, loss, grads):
         grads, grad_norm = opt.clip_by_global_norm(grads, max_grad_norm)
