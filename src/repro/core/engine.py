@@ -679,7 +679,8 @@ class ScoringEngine:
         if self.validation != "off" and n:
             with span("validate"):
                 valid_idx, quarantined = validate_pairs(
-                    pairs, n_labels=self.cfg.n_node_labels)
+                    pairs, n_labels=self.cfg.n_node_labels,
+                    stats=self.counters)
             if quarantined and self.validation == "strict":
                 raise GraphValidationError(quarantined)
         valid = (pairs if len(valid_idx) == n
@@ -1003,7 +1004,8 @@ class ScoringEngine:
         """Inspectable fault-tolerance + planner state (DESIGN.md §12/§15):
         breaker snapshots keyed by path and shape class, error/degradation/
         quarantine counters with the bytes scoring copied to and from the
-        device (`h2d_bytes`, `d2h_bytes`), the embedding-LRU counters, and
+        device (`h2d_bytes`, `d2h_bytes`), the graphs validation checked
+        one by one (`validate_exact_graphs`), the embedding-LRU counters, and
         the measured planner (profile size, fitted model support +
         residuals)."""
         rec = self.recorder
