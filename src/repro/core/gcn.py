@@ -4,8 +4,10 @@ Paper mapping (SPA-GCN §2.1/§3.2): one GCN layer computes
 
     H^{l+1} = ReLU( A' · (H^l · W^l) + b^l )
 
-with the multiplication order A'(HW) chosen over (A'H)W because both operands
-of each product stay sparse-x-dense (fewer ops — same argument as the paper).
+with the bias added after aggregation, as Kipf & Welling and SimGNN's
+released code add it, and the multiplication order A'(HW) chosen over (A'H)W
+because both operands of each product stay sparse-x-dense (fewer ops — same
+argument as the paper).
 On TPU the graphs are processed as a *batch* of padded [N, F] tiles so every
 matmul is a dense MXU-shaped batched GEMM; structural sparsity is removed by
 size-bucketing (see core/batching.py) rather than by dynamic zero-skipping
@@ -56,12 +58,14 @@ def init_gcn_params(key: Array, feature_dims: Sequence[int], dtype=jnp.float32):
 
 def gcn_layer(params, adj_norm: Array, h: Array, mask: Array, *,
               activation: bool = True) -> Array:
-    """One GCN layer on a padded batch. A'(H·W) ordering (paper §3).
+    """One GCN layer on a padded batch: A'(H·W) + b, in A'(H·W) order
+    (paper §3). The bias is added after aggregation; added before it, A'
+    would scale it, off by (A'1 - 1) b.
 
     adj_norm: [B, N, N], h: [B, N, Fin], mask: [B, N] -> [B, N, Fout].
     """
-    hw = jnp.einsum("bnf,fg->bng", h, params["w"]) + params["b"]
-    out = jnp.einsum("bnm,bmg->bng", adj_norm, hw)
+    hw = jnp.einsum("bnf,fg->bng", h, params["w"])
+    out = jnp.einsum("bnm,bmg->bng", adj_norm, hw) + params["b"]
     if activation:
         out = jax.nn.relu(out)
     return out * mask[..., None]
@@ -85,9 +89,10 @@ def gcn_stack_from_labels(layers, adj_norm: Array, labels: Array,
     materializing the [B, N, n_labels] block — the pure-jnp reference for
     the kernels' first-layer one-hot elimination (DESIGN.md §8).
     labels: [B, N] int32 (pad slots may hold any valid label; masked out).
+    Every layer adds its bias after aggregation, as `gcn_layer` does.
     """
-    hw = jnp.take(layers[0]["w"], labels, axis=0) + layers[0]["b"]
-    h = jnp.einsum("bnm,bmg->bng", adj_norm, hw)
+    hw = jnp.take(layers[0]["w"], labels, axis=0)
+    h = jnp.einsum("bnm,bmg->bng", adj_norm, hw) + layers[0]["b"]
     h = jax.nn.relu(h) * mask[..., None]
     for p in layers[1:]:
         h = gcn_layer(p, adj_norm, h, mask, activation=True)

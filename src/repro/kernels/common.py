@@ -199,6 +199,14 @@ def normalize_adjacency_block(adj: jax.Array, mask: jax.Array) -> jax.Array:
     return a_tilde * inv_sqrt[:, :, None] * inv_sqrt[:, None, :]
 
 
+def _add_bias(h: jax.Array, b: jax.Array) -> jax.Array:
+    """[GB, N, F] aggregated features plus the layer's [F] bias. The add
+    runs on the [GB*N, F] view: Mosaic broadcasts a 1-D vector over rows
+    of a 2-D block, not over a 3-D one."""
+    gb, n, f = h.shape
+    return (h.reshape(gb * n, f) + b.astype(jnp.float32)).reshape(gb, n, f)
+
+
 def gcn_layers_block(adj_norm: jax.Array, h: jax.Array | None,
                      mask: jax.Array, layer_wb, *,
                      labels: jax.Array | None = None) -> jax.Array:
@@ -206,6 +214,8 @@ def gcn_layers_block(adj_norm: jax.Array, h: jax.Array | None,
 
     adj_norm [GB, N, N], h [GB, N, F0], mask [GB, N] (fp32) -> [GB, N, F_last].
     layer_wb: list of (w, b) values, any length (SimGNNConfig.gcn_dims).
+    Each layer is ReLU(A'(H·W) + b) * mask: the bias is added after
+    aggregation, in the ReLU-and-mask epilogue (core.gcn.gcn_layer).
 
     With `labels` [GB, N] int32, the first layer's H·W is replaced by a row
     gather of W1 (one_hot(labels) @ W1 == W1[labels] exactly, since one-hot
@@ -224,14 +234,15 @@ def gcn_layers_block(adj_norm: jax.Array, h: jax.Array | None,
             # the whole graph block — (GB*N, Fin) @ (Fin, Fout).
             hw = jnp.dot(h.reshape(gb * n, -1), w.astype(jnp.float32),
                          precision=HIGHEST, preferred_element_type=jnp.float32)
-        hw = (hw + b.astype(jnp.float32)).reshape(gb, n, -1)
+        hw = hw.reshape(gb, n, -1)
         # Aggregation (paper ACG): one batched contraction [GB,N,N]@[GB,N,F]
         # — a single MXU-shaped op instead of a per-graph unrolled dot loop.
         h = jax.lax.dot_general(adj_norm, hw, (((2,), (1,)), ((0,), (0,))),
                                 precision=HIGHEST,
                                 preferred_element_type=jnp.float32)
-        # ReLU + mask: the paper's max(0,.) unit at the ACG output.
-        h = jnp.maximum(h, 0.0) * mask[..., None]
+        # Bias after aggregation, then ReLU + mask: the paper's max(0,.)
+        # unit at the ACG output.
+        h = jnp.maximum(_add_bias(h, b), 0.0) * mask[..., None]
     return h
 
 
@@ -479,11 +490,12 @@ def gcn_layers_edge_block(nbr: jax.Array, nbr_w: jax.Array,
 
     The dense path's per-layer `adj_norm @ (H·W)` batched contraction is
     replaced by `csr_aggregate_block`; the feature transform (H·W matmul,
-    or PR 2's first-layer W1 label gather when int `labels` are given) and
-    the ReLU∘mask epilogue are identical. No adjacency or in-kernel
-    normalization at all: the edge weights are the host-precomputed A'
-    non-zeros (the FPGA host-preprocessing role, paper §3.2.2), so the
-    [GB, N, N] block never exists on-chip.
+    or the first-layer W1 label gather when int `labels` are given) and
+    the epilogue, bias after aggregation then ReLU and mask, are
+    identical. No adjacency or in-kernel normalization at all: the edge
+    weights are the host-precomputed A' non-zeros (the FPGA
+    host-preprocessing role, paper §3.2.2), so the [GB, N, N] block never
+    exists on-chip.
     """
     gb, n = mask.shape
     for li, (w, b) in enumerate(layer_wb):
@@ -494,12 +506,12 @@ def gcn_layers_edge_block(nbr: jax.Array, nbr_w: jax.Array,
         else:
             hw = jnp.dot(h.reshape(gb * n, -1), w.astype(jnp.float32),
                          precision=HIGHEST, preferred_element_type=jnp.float32)
-        hw = (hw + b.astype(jnp.float32)).reshape(gb, n, -1)
+        hw = hw.reshape(gb, n, -1)
         # A' is symmetric here by construction (undirected + self loops),
         # so the sym variant's scatter-free transpose-aggregate backward
         # applies (DESIGN.md §11).
         h = csr_aggregate_block_sym(nbr, nbr_w, ov_snd, ov_rcv, ov_w, hw)
-        h = jnp.maximum(h, 0.0) * mask[..., None]
+        h = jnp.maximum(_add_bias(h, b), 0.0) * mask[..., None]
     return h
 
 

@@ -17,11 +17,13 @@ NEG_INF = -1e30
 def fused_gcn_att_ref(adj_norm: Array, feats: Array, mask: Array,
                       gcn_params, att_w: Array) -> Array:
     """Oracle for kernels/fused_gcn.py: 3 GCN layers + att pooling.
-    Takes the *normalized* adjacency (kernel parity)."""
+    Takes the *normalized* adjacency (kernel parity). Each layer is
+    ReLU(A'(HW) + b) * mask, the bias added after aggregation."""
     h = feats.astype(jnp.float32)
     for p in gcn_params:
-        hw = jnp.einsum("bnf,fg->bng", h, p["w"].astype(jnp.float32)) + p["b"]
-        h = jnp.einsum("bnm,bmg->bng", adj_norm.astype(jnp.float32), hw)
+        hw = jnp.einsum("bnf,fg->bng", h, p["w"].astype(jnp.float32))
+        h = jnp.einsum("bnm,bmg->bng", adj_norm.astype(jnp.float32),
+                       hw) + p["b"]
         h = jax.nn.relu(h) * mask[..., None]
     n_valid = jnp.maximum(jnp.sum(mask, axis=-1, keepdims=True), 1.0)
     mean_h = jnp.sum(h * mask[..., None], axis=-2) / n_valid
