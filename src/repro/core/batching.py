@@ -25,7 +25,9 @@ executable per bucket), mirroring the paper's per-layer customization.
 Every host<->device copy here goes through `to_device`/`to_host`: one
 `engine.to_device`/`engine.to_host` profiler span per call site (carrying
 its byte count), and the bytes added to the `stats` dict the caller passes
-(`h2d_bytes`, `d2h_bytes`), which is how `ScoringEngine` counts them.
+(`h2d_bytes`, `d2h_bytes`), which is how `ScoringEngine` counts them. The
+one exception is the degree table's fill (`_inv_sqrt_degree`), counted by
+`degree_rsqrt_misses` instead.
 """
 
 from __future__ import annotations
@@ -74,7 +76,7 @@ class EdgeBatch(NamedTuple):
         return self.senders.shape[-1]
 
 
-def _add_bytes(stats: dict | None, key: str, n: int) -> None:
+def _add_stat(stats: dict | None, key: str, n: int) -> None:
     if stats is not None:
         stats[key] = stats.get(key, 0) + n
 
@@ -86,7 +88,7 @@ def to_device(arrays: Sequence[np.ndarray],
     nbytes = sum(int(a.nbytes) for a in arrays)
     with span("to_device", bytes=nbytes):
         out = [jnp.asarray(a) for a in arrays]
-    _add_bytes(stats, "h2d_bytes", nbytes)
+    _add_stat(stats, "h2d_bytes", nbytes)
     return out
 
 
@@ -102,7 +104,7 @@ def to_host(arrays: Sequence, stats: dict | None = None) -> list:
     nbytes = sum(int(a.nbytes) for a in on_device)
     with span("to_host", bytes=nbytes):
         out = [np.asarray(a) for a in arrays]
-    _add_bytes(stats, "d2h_bytes", nbytes)
+    _add_stat(stats, "d2h_bytes", nbytes)
     return out
 
 
@@ -182,7 +184,9 @@ class PackedPairBatch(NamedTuple):
     `seg*` maps every node slot to its pair slot (pad slots: segment 0 with
     mask 0, contributing exact zeros to every segment reduction).
     """
-    adj1: Array           # [T, NB, NB] block-diagonal raw adjacency (lhs)
+    adj1: Array           # [T, NB, NB] block-diagonal raw adjacency (lhs);
+                          # host numpy when packed `with_edges=True`
+                          # (the sparse kernels never read it)
     labels1: Array        # [T, NB] int32 node labels (pad 0)
     mask1: Array          # [T, NB] 1.0 for real nodes
     seg1: Array           # [T, NB] int32 pair-slot id in [0, P)
@@ -221,7 +225,10 @@ class PackedEdges(NamedTuple):
     rows ride the vector path, the heavy tail a tiny one-hot contraction.
     Block-diagonality is inherited from the node packing — no edge crosses
     graphs, so segment reductions stay exact. Pad slots carry exact-zero
-    weight/mask (neutral in aggregation).
+    weight/mask (neutral in aggregation). The lists are built on the host
+    (`packed_pair_edges`) and are bitwise the non-zeros of
+    `gcn.normalized_adjacency` on the default backend; they are the only
+    adjacency the packed-sparse path copies to the device.
     """
     edges1: EdgeBatch     # lhs CSR rows, arrays [T, NB*D]
     edges2: EdgeBatch     # rhs CSR rows
@@ -252,14 +259,17 @@ def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
 
     With `with_edges=True` the result additionally carries `edges`: the
     tile-local padded edge list of the normalized adjacency (`PackedEdges`,
-    DESIGN.md §9) that the packed-sparse megakernel aggregates from,
-    extracted by `packed_pair_edges` at a quantized `edge_budget`
+    DESIGN.md §9) that the packed-sparse megakernel aggregates from, built
+    on the host from the tile arrays just filled, as `packed_pair_edges`
+    builds it, at a quantized `edge_budget`
     (node_budget rows x a small neighbor-budget ladder, auto-grown to fit;
     `kernels.ops.packed_edge_budget` is the sizing policy) and an
     `overflow_budget` floor for the COO spill — callers that stream many
     batches pass the previous batch's realized `stats["overflow_budget"]`
-    back in so the compiled [T, E_ov] shape stays put. stats then gains
-    the measured nnz / adjacency density per side.
+    back in so the compiled [T, E_ov] shape stays put. Only the edge
+    lists go to the device: `adj1`/`adj2` stay host numpy arrays, and
+    nothing is read back. stats then gains the nnz / adjacency density per
+    side, counted on the host, and `degree_rsqrt_misses`.
 
     stats: occupancy / pad-fraction per side plus tile shape, and the
     bytes the packing copied to the device (`h2d_bytes`) and back
@@ -323,26 +333,30 @@ def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
         "mean_pairs_per_tile": len(pairs) / n_tiles,
         "h2d_bytes": 0, "d2h_bytes": 0,
     }
-    packed = PackedPairBatch(*to_device(
-        (adj[0], labels[0], mask[0], seg[0],
-         adj[1], labels[1], mask[1], seg[1], pair_mask, pair_index), stats))
-    if with_edges:
-        edges = packed_pair_edges(packed, edge_budget,
-                                  overflow_budget=overflow_budget,
-                                  stats=stats)
-        packed = packed._replace(edges=edges)
-        m1, o1, m2, o2 = to_host(
-            (edges.edges1.edge_mask, edges.overflow1.edge_mask,
-             edges.edges2.edge_mask, edges.overflow2.edge_mask), stats)
-        nnz = [int(m1.sum()) + int(o1.sum()), int(m2.sum()) + int(o2.sum())]
-        adj_cells = n_tiles * node_budget * node_budget
-        stats.update(
-            edge_budget=edges.edge_budget,
-            overflow_budget=edges.overflow_budget,
-            nnz_lhs=nnz[0], nnz_rhs=nnz[1],
-            density_lhs=nnz[0] / adj_cells, density_rhs=nnz[1] / adj_cells,
-            edge_occupancy=(nnz[0] + nnz[1])
-            / max(2 * n_tiles * edges.edge_budget, 1))
+    if not with_edges:
+        packed = PackedPairBatch(*to_device(
+            (adj[0], labels[0], mask[0], seg[0],
+             adj[1], labels[1], mask[1], seg[1], pair_mask, pair_index),
+            stats))
+        return packed, stats
+    # The packed-sparse kernels read the edge lists, never the dense
+    # adjacency: it stays on the host, where the edge lists are built.
+    l1, m1, s1, l2, m2, s2, pm, pi = to_device(
+        (labels[0], mask[0], seg[0], labels[1], mask[1], seg[1],
+         pair_mask, pair_index), stats)
+    with span("edges"):
+        edges, nnz = _packed_pair_edges(((adj[0], mask[0]), (adj[1], mask[1])),
+                                        edge_budget, overflow_budget, stats)
+    packed = PackedPairBatch(adj[0], l1, m1, s1, adj[1], l2, m2, s2, pm, pi,
+                             edges)
+    adj_cells = n_tiles * node_budget * node_budget
+    stats.update(
+        edge_budget=edges.edge_budget,
+        overflow_budget=edges.overflow_budget,
+        nnz_lhs=nnz[0], nnz_rhs=nnz[1],
+        density_lhs=nnz[0] / adj_cells, density_rhs=nnz[1] / adj_cells,
+        edge_occupancy=(nnz[0] + nnz[1])
+        / max(2 * n_tiles * edges.edge_budget, 1))
     return packed, stats
 
 
@@ -353,52 +367,127 @@ def packed_pair_edges(packed: PackedPairBatch,
     """Extract per-tile packed-CSR A' edge lists from a packed tile batch
     (DESIGN.md §9).
 
-    Extracts the same A' non-zeros as `to_edge_batch` (one vectorized
-    nonzero scan per side — this sits on the §11 training hot path) — the
-    packed adjacency is block-diagonal and the masked normalization factors
-    per graph, so each tile's A' non-zeros ARE the union of its graphs' A'
-    non-zeros — then lays the (receiver-sorted) list out in
+    The packed adjacency is block-diagonal and the masked normalization
+    factors per graph, so each tile's A' non-zeros ARE the union of its
+    graphs' A' non-zeros. They are found on the host, with no device round
+    trip: one flat scan per side of the (A + I) structure on real nodes,
+    the weights as `gcn.normalized_adjacency` forms them (`_a_prime`).
+    The (receiver-sorted) list is then laid out in
     D = edge_budget/node_budget ELLPACK neighbor planes (plane d, slot n =
     node n's d-th in-edge); edges beyond a node's D slots spill to the COO
-    overflow list. Budgets are powers of two and
-    auto-grow to fit (`edge_budget=None` sizes D to the realized max
-    in-degree, leaving the overflow empty). Both sides share one budget.
-    Runs in one `engine.edges` span; `stats` receives the bytes of the
-    normalized adjacency read back (`d2h_bytes`) and of the edge lists
-    copied out (`h2d_bytes`).
+    overflow list. Budgets are powers of two and auto-grow to fit
+    (`edge_budget=None` sizes D to the realized max in-degree, leaving the
+    overflow empty). Both sides share one budget.
+
+    `pack_pairs(with_edges=True)` builds the same lists from its host tile
+    arrays; this entry point serves batches packed without edges, whose
+    adjacency and masks are read back once if they are on the device.
+    Runs in one `engine.edges` span; `stats` receives the bytes of any such
+    read-back (`d2h_bytes`), of the edge lists copied out (`h2d_bytes`) and
+    the `degree_rsqrt_misses` of `_inv_sqrt_degree`.
     """
     with span("edges"):
-        return _packed_pair_edges(packed, edge_budget, overflow_budget, stats)
+        sides = [packed.adj1, packed.mask1, packed.adj2, packed.mask2]
+        if any(isinstance(a, jax.Array) for a in sides):
+            sides = to_host(sides, stats)
+        return _packed_pair_edges((sides[:2], sides[2:]), edge_budget,
+                                  overflow_budget, stats)[0]
 
 
-def _packed_pair_edges(packed, edge_budget, overflow_budget, stats):
-    from repro.core.gcn import normalized_adjacency  # late import, no cycle
+#: float32 degree (as its uint32 bits) -> its `gcn.inv_sqrt_degree` entry,
+#: as the default backend computes it. 0/1 graphs of a node budget NB have
+#: at most NB + 1 distinct degrees, so after warm-up every lookup hits and
+#: the host builds A' with no device call at all.
+_INV_SQRT_DEGREE: dict[int, np.float32] = {}
+#: the fixed length misses are padded to: one executable, warmed once.
+_DEGREE_CHUNK = 128
+#: entries kept before the table starts over (weighted graphs).
+_DEGREE_TABLE_CAP = 1 << 16
 
-    nb = packed.node_budget
+
+def _inv_sqrt_degree(deg: np.ndarray, stats: dict | None) -> np.ndarray:
+    """`gcn.inv_sqrt_degree(deg)` for a 1-D host float32 `deg`, bitwise as the
+    default backend computes it (its rsqrt need not be correctly rounded,
+    so numpy's would not do): each distinct degree is looked up in
+    `_INV_SQRT_DEGREE`, and the misses are evaluated on the device in
+    `_DEGREE_CHUNK`-long padded batches, their number added to
+    `stats["degree_rsqrt_misses"]`. These table fills are not counted as
+    transfer bytes: 512 bytes each way per 128 new degrees, once."""
+    from repro.core.gcn import inv_sqrt_degree  # late import, no cycle
+
+    keys, inverse = np.unique(deg.view(np.uint32), return_inverse=True)
+    keys = keys.tolist()
+    known = [_INV_SQRT_DEGREE.get(k) for k in keys]
+    missing = [k for k, v in zip(keys, known) if v is None]
+    new: dict[int, np.float32] = {}
+    for i in range(0, len(missing), _DEGREE_CHUNK):
+        part = np.asarray(missing[i:i + _DEGREE_CHUNK], np.uint32)
+        padded = np.ones(_DEGREE_CHUNK, np.float32)
+        padded[:part.size] = part.view(np.float32)
+        inv = np.asarray(inv_sqrt_degree(jnp.asarray(padded)))[:part.size]
+        new.update(zip(part.tolist(), inv))
+    if new:
+        if len(_INV_SQRT_DEGREE) + len(new) > _DEGREE_TABLE_CAP:
+            _INV_SQRT_DEGREE.clear()
+        _INV_SQRT_DEGREE.update(new)
+    _add_stat(stats, "degree_rsqrt_misses", len(missing))
+    table = np.asarray([new[k] if v is None else v
+                        for k, v in zip(keys, known)], np.float32)
+    return table[inverse.reshape(-1)]
+
+
+def _a_prime(adj: np.ndarray, mask: np.ndarray, stats: dict | None):
+    """The non-zeros of A' = `gcn.normalized_adjacency(adj, mask)` for host
+    tile arrays `adj` [T, NB, NB] and `mask` [T, NB], as (tiles, rows, cols,
+    weights) in row-major order, so sorted by (tile, receiver). Each step is
+    the device expression's, in its order and precision: a_tilde =
+    (adj + I) * (mask_r * mask_c), degree = its row sum, weight =
+    a_tilde * inv_r * inv_c in float32. The degree is summed in float64 and
+    rounded once; that is every float32 order's sum whenever the row sums
+    exactly, as 0/1 and small dyadic weights do."""
+    t, nb, _ = adj.shape
+    diag = np.arange(nb)
+    nz = adj != 0
+    nz[:, diag, diag] = True            # A + I: every diagonal is a candidate
+    flat = np.flatnonzero(nz)
+    tiles, cell = np.divmod(flat, nb * nb)
+    rows, cols = np.divmod(cell, nb)
+    a_tilde = ((adj.reshape(-1)[flat] + (rows == cols))
+               * (mask[tiles, rows] * mask[tiles, cols]))
+    recv = tiles * nb + rows
+    deg = np.bincount(recv, weights=a_tilde, minlength=t * nb)
+    inv = _inv_sqrt_degree(deg.astype(np.float32), stats)
+    w = a_tilde * inv[recv] * inv[tiles * nb + cols]
+    keep = w != 0                       # exact zeros of A' are not edges
+    return tiles[keep], rows[keep], cols[keep], w[keep]
+
+
+def _packed_pair_edges(sides, edge_budget, overflow_budget, stats):
+    """`packed_pair_edges` on host `((adj1, mask1), (adj2, mask2))`; returns
+    the device `PackedEdges` and each side's non-zero count."""
+    nb = sides[0][0].shape[-1]
     if edge_budget is not None and edge_budget % nb:
         raise ValueError(f"edge_budget {edge_budget} must be a multiple of "
                          f"node_budget {nb} (CSR rows)")
     d_budget = (edge_budget // nb) if edge_budget else 1
     # Fully vectorized extraction (no per-tile Python loop — the host pack
-    # sits on the training hot path since DESIGN.md §11): one nonzero scan
-    # per side; np.nonzero returns row-major order, so edges arrive sorted
-    # by (tile, receiver) and the in-row rank is a searchsorted subtraction.
-    sides = []
-    for adj, mask in ((packed.adj1, packed.mask1), (packed.adj2, packed.mask2)):
-        a_norm = to_host((normalized_adjacency(adj, mask),), stats)[0]
-        t = a_norm.shape[0]
-        tiles, rows, cols = np.nonzero(a_norm)
-        w = a_norm[tiles, rows, cols].astype(np.float32)
-        key = tiles.astype(np.int64) * nb + rows
+    # sits on the training hot path since DESIGN.md §11): edges arrive
+    # sorted by (tile, receiver), so the in-row rank is a searchsorted
+    # subtraction.
+    found = []
+    for adj, mask in sides:
+        t = adj.shape[0]
+        tiles, rows, cols, w = _a_prime(adj, mask, stats)
+        key = tiles * nb + rows
         rank = np.arange(key.size) - np.searchsorted(key, key, side="left")
         max_rank = int(rank.max()) + 1 if key.size else 0
-        sides.append((t, tiles, rows, cols, w, rank, max_rank))
+        found.append((t, tiles, rows, cols, w, rank, max_rank))
 
     d = max(d_budget, 1)
     if edge_budget is None:
-        d = next_pow2(max(s[6] for s in sides), floor=2)
+        d = next_pow2(max(s[6] for s in found), floor=2)
     ov_need = 0
-    for t, tiles, rows, cols, w, rank, _ in sides:
+    for t, tiles, rows, cols, w, rank, _ in found:
         spill = rank >= d
         if spill.any():
             ov_need = max(ov_need, int(np.bincount(tiles[spill]).max()))
@@ -410,8 +499,8 @@ def _packed_pair_edges(packed, edge_budget, overflow_budget, stats):
     # promote against int32 iotas/offsets, so scores are bit-identical
     # (pinned by the int16 row of the sharded parity matrix).
     idx_dtype = np.int16 if nb < 2 ** 15 else np.int32
-    out = []
-    for t, tiles, rows, cols, w, rank, _ in sides:
+    planes = []
+    for t, tiles, rows, cols, w, rank, _ in found:
         cs = np.zeros((t, nb * d), idx_dtype)
         cr = np.tile(np.tile(np.arange(nb, dtype=idx_dtype), d), (t, 1))
         cw = np.zeros((t, nb * d), np.float32)
@@ -435,9 +524,11 @@ def _packed_pair_edges(packed, edge_budget, overflow_budget, stats):
             or_.reshape(-1)[oslot] = rows[~fit]
             ow.reshape(-1)[oslot] = w[~fit]
             om.reshape(-1)[oslot] = 1.0
-        arrays = to_device((cs, cr, cw, cm, os_, or_, ow, om), stats)
-        out.append((EdgeBatch(*arrays[:4]), EdgeBatch(*arrays[4:])))
-    return PackedEdges(out[0][0], out[1][0], out[0][1], out[1][1])
+        planes += [cs, cr, cw, cm, os_, or_, ow, om]
+    a = to_device(planes, stats)
+    edges = PackedEdges(EdgeBatch(*a[0:4]), EdgeBatch(*a[8:12]),
+                        EdgeBatch(*a[4:8]), EdgeBatch(*a[12:16]))
+    return edges, (found[0][4].size, found[1][4].size)
 
 
 def unpack_pair_scores(scores_tp, packed: PackedPairBatch,

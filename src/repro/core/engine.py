@@ -895,6 +895,9 @@ class ScoringEngine:
                                     overflow_budget=self._overflow_floor)
         self._overflow_floor = max(self._overflow_floor,
                                    pstats["overflow_budget"])
+        if pstats["degree_rsqrt_misses"]:
+            self.counters["degree_rsqrt_misses"] += (
+                pstats["degree_rsqrt_misses"])
         return packed, pstats
 
     # ------------------------------------- degradation + breakers (§12)
@@ -1005,7 +1008,9 @@ class ScoringEngine:
         breaker snapshots keyed by path and shape class, error/degradation/
         quarantine counters with the bytes scoring copied to and from the
         device (`h2d_bytes`, `d2h_bytes`), the graphs validation checked
-        one by one (`validate_exact_graphs`), the embedding-LRU counters, and
+        one by one (`validate_exact_graphs`), the degrees sparse packing
+        sent to the device for their rsqrt (`degree_rsqrt_misses`, present
+        once nonzero), the embedding-LRU counters, and
         the measured planner (profile size, fitted model support +
         residuals)."""
         rec = self.recorder

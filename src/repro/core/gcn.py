@@ -39,8 +39,16 @@ def normalized_adjacency(adj: Array, mask: Array) -> Array:
     eye = jnp.eye(adj.shape[-1], dtype=adj.dtype)
     a_tilde = (adj + eye) * m                              # self loops on real nodes only
     deg = jnp.sum(a_tilde, axis=-1)                        # [B, N]
-    inv_sqrt = jnp.where(deg > 0, jax.lax.rsqrt(jnp.maximum(deg, 1e-12)), 0.0)
+    inv_sqrt = inv_sqrt_degree(deg)
     return a_tilde * inv_sqrt[..., :, None] * inv_sqrt[..., None, :]
+
+
+def inv_sqrt_degree(deg: Array) -> Array:
+    """The diagonal of D^-1/2 in `normalized_adjacency`: rsqrt of each
+    positive degree, 0 for the rest. `core.batching` evaluates this same
+    expression for the A' weights it builds on the host, so that they stay
+    bitwise equal to the device's."""
+    return jnp.where(deg > 0, jax.lax.rsqrt(jnp.maximum(deg, 1e-12)), 0.0)
 
 
 def init_gcn_params(key: Array, feature_dims: Sequence[int], dtype=jnp.float32):

@@ -70,7 +70,8 @@ from repro.core.store import StoreError, atomic_write_bytes
 #:   select      the dispatch decision, cost-model refits and the
 #:               fit/oversize split
 #:   pack        tile packing or size bucketing and the host array fill
-#:   edges       `packed_pair_edges` (packed-sparse path)
+#:   edges       the host build of the A' edge lists and their copy
+#:               (`packed_pair_edges`, packed-sparse path)
 #:   to_device   host -> device copies of one call site; carries `bytes`
 #:   to_host     device -> host copies of one call site; carries `bytes`
 #:   kernel      the call into an executor, up to its return (dispatch)
