@@ -303,9 +303,7 @@ def phase_sharded(smoke: Smoke, params, cfg, seed: int, n: int) -> dict:
     t = packed.mask1.shape[0]
     target, tb = ops.sharded_tile_plan(t, many.node_budget, n, sparse=True)
     fn = many._sharded_fn("packed_sparse", n, tb)
-    arrays = [ops._pad_batch(x, target)[0]
-              for x in many._packed_score_arrays(packed, True)]
-    out = fn(params, *arrays)
+    out = fn(params, ops.pad_tiles(packed.kernel_operands(True), target))
     shard_devs = sorted(s.device.id for s in out.addressable_shards)
     spans = sorted({s.data.shape[0] for s in out.addressable_shards})
     smoke.check(shard_devs == mesh_ids and spans == [target // n],

@@ -209,6 +209,26 @@ class PackedPairBatch(NamedTuple):
     def slots_per_tile(self) -> int:
         return self.pair_mask.shape[-1]
 
+    def kernel_operands(self, sparse: bool) -> tuple:
+        """The tile-leading operands of the packed megakernels, in the
+        order `sparse_pair_score`/`packed_pair_score` (and their grad twins
+        in `kernels/grad.py`) take them: per side the packed-CSR edge planes
+        (sparse, from `edges`) or the dense adjacency, then labels, mask and
+        segment ids; `pair_mask` last. The one place this order is written."""
+        if sparse:
+            e1, e2 = self.edges.edges1, self.edges.edges2
+            o1, o2 = self.edges.overflow1, self.edges.overflow2
+            return (e1.senders, e1.weights,
+                    o1.senders, o1.receivers, o1.weights,
+                    self.labels1, self.mask1, self.seg1,
+                    e2.senders, e2.weights,
+                    o2.senders, o2.receivers, o2.weights,
+                    self.labels2, self.mask2, self.seg2,
+                    self.pair_mask)
+        return (self.adj1, self.labels1, self.mask1, self.seg1,
+                self.adj2, self.labels2, self.mask2, self.seg2,
+                self.pair_mask)
+
 
 class PackedEdges(NamedTuple):
     """Packed-CSR view of a packed tile batch's *normalized* adjacency

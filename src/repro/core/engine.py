@@ -602,11 +602,9 @@ class ScoringEngine:
         if fn is None:
             from repro.kernels import ops
 
-            build = (ops.build_pair_score_sparse_sharded
-                     if path == "packed_sparse"
-                     else ops.build_pair_score_packed_sharded)
-            fn, _ = build(self._tile_mesh(devices), self.node_budget,
-                          tile_block=tile_block)
+            fn, _ = ops.build_pair_score_sharded(
+                self._tile_mesh(devices), self.node_budget,
+                sparse=path == "packed_sparse", tile_block=tile_block)
             self._sharded_fns[key] = fn
         return fn
 
@@ -792,69 +790,44 @@ class ScoringEngine:
             out[idx[idxs]] = to_host((s,), self.counters)[0]
 
     def _score_packed(self, pairs, idx: np.ndarray, out: np.ndarray,
-                      sparse: bool, stats: WorkloadStats):
-        from repro.core.batching import unpack_pair_scores
-        from repro.kernels import ops
-
-        packed, pstats = self._pack(pairs, sparse, stats)
-        score = ops.pair_score_sparse if sparse else ops.pair_score_packed
-        with span("kernel"):
-            s = _call("packed_sparse" if sparse else "packed_dense",
-                      lambda: score(self.params, packed, quantize_tiles=True))
-        self.last_pack_stats = pstats
-        with span("unpack"):
-            out[idx] = unpack_pair_scores(s, packed, len(pairs),
-                                          stats=self.counters)
-
-    @staticmethod
-    def _packed_score_arrays(packed, sparse: bool) -> tuple:
-        """The positional array tuple a packed megakernel takes, in kernel
-        order (shared by the sharded executors and `kernels.ops`)."""
-        if sparse:
-            e1, e2 = packed.edges.edges1, packed.edges.edges2
-            o1, o2 = packed.edges.overflow1, packed.edges.overflow2
-            return (e1.senders, e1.weights,
-                    o1.senders, o1.receivers, o1.weights,
-                    packed.labels1, packed.mask1, packed.seg1,
-                    e2.senders, e2.weights,
-                    o2.senders, o2.receivers, o2.weights,
-                    packed.labels2, packed.mask2, packed.seg2,
-                    packed.pair_mask)
-        return (packed.adj1, packed.labels1, packed.mask1, packed.seg1,
-                packed.adj2, packed.labels2, packed.mask2, packed.seg2,
-                packed.pair_mask)
-
-    def _score_packed_sharded(self, pairs, idx: np.ndarray, out: np.ndarray,
-                              sparse: bool, stats: WorkloadStats,
-                              devices: int):
-        """Packed scoring with the tile axis sharded over `devices` mesh
-        devices (DESIGN.md §16): pack host-side exactly as the unsharded
-        executor, pad T to a power-of-two >= devices x tile_block, run the
-        shard_map executor, gather [T, P] scores host-side. The fault site
-        is `sharded:<path>` — a dead shard surfaces here and the §12 ladder
-        collapses the call to the single-device rung."""
+                      sparse: bool, stats: WorkloadStats, devices: int = 1):
+        """Packed scoring: pack host-side, score the tiles, unpack. On one
+        device the `kernels.ops` executor pads and runs the kernel under
+        fault site `<path>`. With `devices > 1` the tile axis is sharded
+        over that many mesh devices (DESIGN.md §16): T pads to the
+        `ops.sharded_tile_plan` target, the cached shard_map executor runs
+        under site `sharded:<path>` — a dead shard surfaces there and the
+        §12 ladder collapses the call to the single-device rung — and
+        `last_pack_stats` gains the per-device tile spans."""
         from repro.core.batching import unpack_pair_scores
         from repro.kernels import ops
 
         path = "packed_sparse" if sparse else "packed_dense"
         packed, pstats = self._pack(pairs, sparse, stats)
-        t = packed.mask1.shape[0]
-        target, tile_block = ops.sharded_tile_plan(
-            t, self.node_budget, devices, sparse=sparse)
-        fn = self._sharded_fn(path, devices, tile_block)
-        with span("kernel"):
-            arrays = [ops._pad_batch(x, target)[0]
-                      for x in self._packed_score_arrays(packed, sparse)]
-            s = _call(f"sharded:{path}",
-                      lambda: fn(self.params, *arrays))[:t]
-        per_device = target // devices
-        self.last_pack_stats = dict(
-            pstats, devices=devices, tiles=t, tiles_padded=target,
-            # live-tile fraction of each device's span (pad tiles append at
-            # the end, so trailing devices absorb the padding waste).
-            device_occupancy=[
-                max(0, min(t - d * per_device, per_device)) / per_device
-                for d in range(devices)])
+        if devices > 1:
+            t = packed.mask1.shape[0]
+            target, tile_block = ops.sharded_tile_plan(
+                t, self.node_budget, devices, sparse=sparse)
+            fn = self._sharded_fn(path, devices, tile_block)
+            with span("kernel"):
+                operands = ops.pad_tiles(packed.kernel_operands(sparse),
+                                         target)
+                s = _call(f"sharded:{path}",
+                          lambda: fn(self.params, operands))[:t]
+            per_device = target // devices
+            pstats = dict(
+                pstats, devices=devices, tiles=t, tiles_padded=target,
+                # live-tile fraction of each device's span (pad tiles append
+                # at the end, so trailing devices absorb the padding waste).
+                device_occupancy=[
+                    max(0, min(t - d * per_device, per_device)) / per_device
+                    for d in range(devices)])
+        else:
+            score = ops.pair_score_sparse if sparse else ops.pair_score_packed
+            with span("kernel"):
+                s = _call(path, lambda: score(self.params, packed,
+                                              quantize_tiles=True))
+        self.last_pack_stats = pstats
         with span("unpack"):
             out[idx] = unpack_pair_scores(s, packed, len(pairs),
                                           stats=self.counters)
@@ -922,13 +895,9 @@ class ScoringEngine:
 
     def _execute_rung(self, rung: str, devices: int, sub, idx: np.ndarray,
                       out: np.ndarray, plan: ScorePlan):
-        if rung in PACKED_PATHS and devices > 1:
-            self._score_packed_sharded(sub, idx, out,
-                                       rung == "packed_sparse",
-                                       plan.stats, devices)
-        elif rung in PACKED_PATHS:
+        if rung in PACKED_PATHS:
             self._score_packed(sub, idx, out, rung == "packed_sparse",
-                               plan.stats)
+                               plan.stats, devices)
         elif rung == "embedding_cache":
             self._score_cached(sub, idx, out, plan)
         else:
@@ -1040,11 +1009,12 @@ class ScoringEngine:
         """One jitted value_and_grad executor per (train path, chunk size,
         device count, gradient-function kind) — cached on the engine like
         `bucket_fns`, so a training loop reuses one executable per padded
-        shape. The function maps (params, targets, *arrays) -> (sum of
-        squared errors, d/dparams), scanning `chunk_tiles`-tile chunks of
-        the packed batch (cache blocking AND accumulation microbatching in
-        one mechanism — the packed planes are packed once and only the scan
-        slice moves).
+        shape. A packed path's function maps (params, targets, operands)
+        -> (sum of squared errors, d/dparams), `operands` being
+        `PackedPairBatch.kernel_operands`, scanning `chunk_tiles`-tile
+        chunks of the packed batch (cache blocking AND accumulation
+        microbatching in one mechanism — the packed planes are packed once
+        and only the scan slice moves).
 
         The raw loss -> value_and_grad transform is delegated to the
         engine's swappable `grad_fn` object (`train/sgf.py`, paxml-style),
@@ -1072,27 +1042,27 @@ class ScoringEngine:
                             if path == "packed_sparse"
                             else kgrad.packed_pair_score_grad)
 
-                def sse(params, tgt, *arrays):
+                def sse(params, tgt, operands):
                     # Pad pair slots score exact zero against target zero.
-                    return jnp.sum((score_fn(params, *arrays) - tgt) ** 2)
+                    return jnp.sum((score_fn(params, *operands) - tgt) ** 2)
 
             grad_fn = self.grad_fn.value_and_grad(sse)
             if path == "reference":
                 fn = grad_fn
             else:
-                def fn(params, tgt, *arrays):
+                def fn(params, tgt, operands):
                     t = tgt.shape[0]
                     n_chunks = t // chunk_tiles
                     if n_chunks <= 1:
-                        return grad_fn(params, tgt, *arrays)
+                        return grad_fn(params, tgt, operands)
 
                     def chunk(x):
                         return x.reshape((n_chunks, chunk_tiles)
                                          + x.shape[1:])
-                    xs = tuple(chunk(x) for x in (tgt,) + arrays)
+                    xs = jax.tree.map(chunk, (tgt, operands))
 
                     def micro(acc, mb):
-                        s, g = grad_fn(params, mb[0], *mb[1:])
+                        s, g = grad_fn(params, *mb)
                         return (acc[0] + s,
                                 jax.tree.map(jnp.add, acc[1], g)), None
                     zero = (jnp.zeros((), jnp.float32),
@@ -1106,16 +1076,14 @@ class ScoringEngine:
 
                     from repro.distributed.sharding import TILE_AXIS
 
-                    n_arrays = 17 if path == "packed_sparse" else 9
                     scan = fn
 
-                    def local(params, tgt, *arrays):
-                        return jax.lax.psum(scan(params, tgt, *arrays),
+                    def local(params, tgt, operands):
+                        return jax.lax.psum(scan(params, tgt, operands),
                                             TILE_AXIS)
                     fn = jax.shard_map(
                         local, mesh=self._tile_mesh(devices),
-                        in_specs=(P(), P(TILE_AXIS))
-                        + (P(TILE_AXIS),) * n_arrays,
+                        in_specs=(P(), P(TILE_AXIS), P(TILE_AXIS)),
                         out_specs=P(), check_vma=False)
             self._train_fns[key] = jax.jit(fn)
         return self._train_fns[key]
@@ -1134,7 +1102,7 @@ class ScoringEngine:
         import jax.numpy as jnp
 
         from repro.core.batching import next_pow2, pack_pairs
-        from repro.kernels import grad as kgrad
+        from repro.kernels import ops
 
         path = plan.path if path is None else path
         sparse = path == "packed_sparse"
@@ -1163,25 +1131,16 @@ class ScoringEngine:
         chunk_tiles = min(self.TRAIN_TILE_CHUNK, next_pow2(t, floor=1))
         while chunk_tiles > 1 and (-(-t // chunk_tiles)) < accum_steps:
             chunk_tiles //= 2
-        pad = (-t) % (chunk_tiles * max(devices, 1))
-
-        def pad_tiles(x):
-            if not pad:
-                return x
-            return jnp.concatenate(
-                [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)], 0)
-
-        arrays = tuple(pad_tiles(x)
-                       for x in kgrad.packed_arrays(packed, sparse=sparse))
+        multiple = chunk_tiles * max(devices, 1)
+        operands = ops.pad_tiles(packed.kernel_operands(sparse), multiple)
         if devices > 1:
             self.last_pack_stats = dict(pstats, devices=devices, tiles=t,
-                                        tiles_padded=t + pad)
+                                        tiles_padded=operands[0].shape[0])
         fn = self._train_fn(path, chunk_tiles, devices)
         site = (f"sharded:train:{path}" if devices > 1
                 else f"train:{path}")
-        return _call(site,
-                     lambda: fn(params, pad_tiles(jnp.asarray(tgt)),
-                                *arrays))
+        return _call(site, lambda: fn(
+            params, ops._pad_batch(jnp.asarray(tgt), multiple)[0], operands))
 
     def _reference_sse(self, params, pairs, targets: np.ndarray):
         """SSE + grads of the dense-reference executor (the train-mode

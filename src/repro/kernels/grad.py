@@ -11,13 +11,14 @@ bodies under `jit` instead of under `pallas_call`:
   * `packed_pair_score_grad`  — the §8 dense block-diagonal tile path;
   * `sparse_pair_score_grad`  — the §9 packed-CSR edge-centric path.
 
-Both consume the exact `core.batching.pack_pairs` layouts the inference
-kernels consume and return the same `[T, P]` pair-slot scores (zero at pad
-slots), so one packing pass per training batch serves the forward AND
-backward passes of every accumulation microbatch. On TPU the bodies lower
-to the same MXU-shaped contractions XLA would fuse anyway; what the Pallas
-wrapper adds for inference (explicit VMEM residency across stages) is
-redundant under autodiff, which must spill residuals to HBM regardless.
+Both take the exact operands the inference kernels take
+(`core.batching.PackedPairBatch.kernel_operands`) and return the same
+`[T, P]` pair-slot scores (zero at pad slots), so one packing pass per
+training batch serves the forward AND backward passes of every
+accumulation microbatch. On TPU the bodies lower to the same MXU-shaped
+contractions XLA would fuse anyway; what the Pallas wrapper adds for
+inference (explicit VMEM residency across stages) is redundant under
+autodiff, which must spill residuals to HBM regardless.
 
 `core.engine.ScoringEngine.loss_and_grad` is the dispatch point; nothing
 else should import these directly.
@@ -112,22 +113,3 @@ def sparse_pair_score_grad(params,
     hg = segment_att_pool_block(h, mask, seg, params["att"]["w"], p)
     return _head_scores(params, hg, t, p, pair_mask)
 
-
-def packed_arrays(packed, *, sparse: bool) -> tuple:
-    """Flatten a PackedPairBatch into the positional array tuple the
-    matching `*_score_grad` function takes (after `pair_mask`-last ordering
-    the jitted loss closures rely on)."""
-    if sparse:
-        e = packed.edges
-        return (e.edges1.senders, e.edges1.weights,
-                e.overflow1.senders, e.overflow1.receivers,
-                e.overflow1.weights,
-                packed.labels1, packed.mask1, packed.seg1,
-                e.edges2.senders, e.edges2.weights,
-                e.overflow2.senders, e.overflow2.receivers,
-                e.overflow2.weights,
-                packed.labels2, packed.mask2, packed.seg2,
-                packed.pair_mask)
-    return (packed.adj1, packed.labels1, packed.mask1, packed.seg1,
-            packed.adj2, packed.labels2, packed.mask2, packed.seg2,
-            packed.pair_mask)

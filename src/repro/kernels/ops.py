@@ -22,6 +22,12 @@ For SimGNN pair scoring there are four kernel paths (path selection lives in
   * `simgnn_pair_score_kernel` — the two-kernel composition (fused GCN+Att,
     then fused NTN+FCN head) kept as building blocks for embedding-only /
     head-only callers and as the benchmark comparison point.
+
+Both packed paths share one executor: `_packed_kernel` runs either kernel
+on `core.batching.PackedPairBatch.kernel_operands` (which owns the operand
+order), on one device under `pair_score_packed`/`pair_score_sparse` and per
+shard under `build_pair_score_sharded` (DESIGN.md §16); `pad_tiles` is the
+one tile-axis pad.
 """
 
 from __future__ import annotations
@@ -50,11 +56,8 @@ __all__ = ["flash_attention", "wkv6", "graph_embeddings_fused",
            "pair_score_packed", "packed_node_budget", "packed_tile_block",
            "pair_score_sparse", "packed_edge_budget", "sparse_tile_block",
            "blocked_topm", "blocked_topm_ntn", "collapse_query_ntn",
-           "retrieval_block_cols", "sharded_tile_block",
-           "sharded_tile_plan",
-           "sharded_tile_target", "build_pair_score_packed_sharded",
-           "build_pair_score_sparse_sharded", "pair_score_packed_sharded",
-           "pair_score_sparse_sharded"]
+           "retrieval_block_cols", "pad_tiles", "sharded_tile_block",
+           "sharded_tile_plan", "build_pair_score_sharded"]
 
 
 def _pad_batch(x: jax.Array, multiple: int) -> tuple[jax.Array, int]:
@@ -166,11 +169,7 @@ def _tile_pad_plan(t: int, tile_block: int,
     `quantize_tiles` rounds T up to the next power of two so a serving loop
     with varying batch sizes compiles O(log T) executables instead of one
     per tile count (the 'small, fixed set of shapes' principle)."""
-    target = t
-    if quantize_tiles:
-        target = 1
-        while target < t:
-            target *= 2
+    target = _pow2_ceil(t) if quantize_tiles else t
     tile_block = min(tile_block, target)
     # Pad-tile waste is real kernel work: halve tile_block until the rounding
     # waste is <= t/8 (always true once tile_block divides target).
@@ -181,6 +180,40 @@ def _tile_pad_plan(t: int, tile_block: int,
     return -(-target // tile_block) * tile_block, tile_block
 
 
+def pad_tiles(operands, multiple: int) -> tuple:
+    """Zero-pad every operand's leading tile axis to a `multiple` multiple
+    (pad tiles carry all-zero masks, so `pair_mask` zeroes their slots)."""
+    return tuple(_pad_batch(x, multiple)[0] for x in operands)
+
+
+def _packed_kernel(params, operands, *, sparse: bool, tile_block: int,
+                   interpret: bool | None) -> jax.Array:
+    """Run the packed-sparse (`sparse`) or packed-dense megakernel on
+    `PackedPairBatch.kernel_operands` already padded to a tile_block
+    multiple: the one call site of both kernels, on one device or per shard
+    (`build_pair_score_sharded`)."""
+    kernel = sparse_pair_score if sparse else packed_pair_score
+    return kernel(*operands, params["gcn"], params["att"]["w"],
+                  params["ntn"], params["fcn"],
+                  tile_block=tile_block, interpret=interpret)
+
+
+def _pair_score_tiles(params, packed, *, sparse: bool,
+                      tile_block: int | None, quantize_tiles: bool,
+                      interpret: bool | None) -> jax.Array:
+    """The single-device packed executor body: pick the tile block, plan
+    the tile padding (`_tile_pad_plan`), pad every operand, run the kernel
+    and slice the live [T, P] tiles back out."""
+    if tile_block is None:
+        tile_block = (sparse_tile_block if sparse
+                      else packed_tile_block)(packed.node_budget)
+    t = packed.mask1.shape[0]
+    target, tile_block = _tile_pad_plan(t, tile_block, quantize_tiles)
+    operands = pad_tiles(packed.kernel_operands(sparse), target)
+    return _packed_kernel(params, operands, sparse=sparse,
+                          tile_block=tile_block, interpret=interpret)[:t]
+
+
 def pair_score_packed(params, packed, *, tile_block: int | None = None,
                       quantize_tiles: bool = False,
                       interpret: bool | None = None) -> jax.Array:
@@ -189,18 +222,10 @@ def pair_score_packed(params, packed, *, tile_block: int | None = None,
     tile_block multiple (pad tiles carry all-zero masks; `pair_mask` zeroes
     their slots). Use `core.batching.unpack_pair_scores` to restore the
     original pair order. See `_tile_pad_plan` for `quantize_tiles`."""
-    if tile_block is None:
-        tile_block = packed_tile_block(packed.node_budget)
-    t = packed.adj1.shape[0]
-    target, tile_block = _tile_pad_plan(t, tile_block, quantize_tiles)
-    arrays = [_pad_batch(x, target)[0]
-              for x in (packed.adj1, packed.labels1, packed.mask1, packed.seg1,
-                        packed.adj2, packed.labels2, packed.mask2, packed.seg2,
-                        packed.pair_mask)]
-    out = packed_pair_score(*arrays, params["gcn"], params["att"]["w"],
-                            params["ntn"], params["fcn"],
-                            tile_block=tile_block, interpret=interpret)
-    return out[:t]
+    return _pair_score_tiles(params, packed, sparse=False,
+                             tile_block=tile_block,
+                             quantize_tiles=quantize_tiles,
+                             interpret=interpret)
 
 
 def sparse_tile_block(node_budget: int) -> int:
@@ -247,28 +272,13 @@ def pair_score_sparse(params, packed, *, tile_block: int | None = None,
     edge lists are extracted here at the default `packed_edge_budget`."""
     from repro.core.batching import packed_pair_edges
 
-    edges = packed.edges
-    if edges is None:
-        edges = packed_pair_edges(packed,
-                                  packed_edge_budget(packed.node_budget))
-    if tile_block is None:
-        tile_block = sparse_tile_block(packed.node_budget)
-    t = packed.mask1.shape[0]
-    target, tile_block = _tile_pad_plan(t, tile_block, quantize_tiles)
-    e1, e2 = edges.edges1, edges.edges2
-    o1, o2 = edges.overflow1, edges.overflow2
-    arrays = [_pad_batch(x, target)[0]
-              for x in (e1.senders, e1.weights,
-                        o1.senders, o1.receivers, o1.weights,
-                        packed.labels1, packed.mask1, packed.seg1,
-                        e2.senders, e2.weights,
-                        o2.senders, o2.receivers, o2.weights,
-                        packed.labels2, packed.mask2, packed.seg2,
-                        packed.pair_mask)]
-    out = sparse_pair_score(*arrays, params["gcn"], params["att"]["w"],
-                            params["ntn"], params["fcn"],
-                            tile_block=tile_block, interpret=interpret)
-    return out[:t]
+    if packed.edges is None:
+        packed = packed._replace(edges=packed_pair_edges(
+            packed, packed_edge_budget(packed.node_budget)))
+    return _pair_score_tiles(params, packed, sparse=True,
+                             tile_block=tile_block,
+                             quantize_tiles=quantize_tiles,
+                             interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -302,7 +312,7 @@ def _pow2_ceil(n: int) -> int:
 
 
 def sharded_tile_block(node_budget: int, *, sparse: bool = False) -> int:
-    """Tiles-per-program ceiling for the sharded wrappers: the
+    """Tiles-per-program ceiling for the sharded executor: the
     single-device VMEM policy rounded down to a power of two (see block
     comment above)."""
     tb = (sparse_tile_block if sparse else packed_tile_block)(node_budget)
@@ -323,20 +333,15 @@ def sharded_tile_plan(t: int, node_budget: int, n_devices: int, *,
     return max(target, int(n_devices) * tb), tb
 
 
-def sharded_tile_target(t: int, tile_block: int, n_devices: int) -> int:
-    """Padded tile count for a sharded call: power-of-two >= t, and at least
-    one tile_block program per device."""
-    return max(_pow2_ceil(max(t, 1)), int(n_devices) * tile_block)
-
-
-def build_pair_score_packed_sharded(mesh: Mesh, node_budget: int, *,
-                                    tile_block: int | None = None,
-                                    interpret: bool | None = None):
-    """Returns (fn, tile_block): `fn(params, adj1, labels1, mask1, seg1,
-    adj2, labels2, mask2, seg2, pair_mask)` scoring tiles sharded over the
-    mesh's `tile` axis. Inputs must be padded to a `sharded_tile_target`
-    multiple; output is the full padded [T, P] score block (caller slices).
-    `tile_block` defaults to the VMEM policy ceiling; callers pass the
+def build_pair_score_sharded(mesh: Mesh, node_budget: int, *, sparse: bool,
+                             tile_block: int | None = None,
+                             interpret: bool | None = None):
+    """Returns (fn, tile_block): `fn(params, operands)` scores
+    `PackedPairBatch.kernel_operands(sparse)` with the tile axis sharded
+    over the mesh's `tile` axis, each shard running `_packed_kernel`.
+    Operands must be padded to the `sharded_tile_plan` target; the output
+    is the full padded [T, P] score block (caller slices). `tile_block`
+    defaults to the VMEM policy ceiling; callers pass the
     `sharded_tile_plan` block to balance few tiles over many devices.
 
     check_vma=False: pallas_call carries no replication rule, and every
@@ -344,92 +349,9 @@ def build_pair_score_packed_sharded(mesh: Mesh, node_budget: int, *,
     from repro.distributed.sharding import TILE_AXIS
 
     if tile_block is None:
-        tile_block = sharded_tile_block(node_budget)
-
-    def local(params, adj1, labels1, mask1, seg1,
-              adj2, labels2, mask2, seg2, pair_mask):
-        return packed_pair_score(adj1, labels1, mask1, seg1,
-                                 adj2, labels2, mask2, seg2, pair_mask,
-                                 params["gcn"], params["att"]["w"],
-                                 params["ntn"], params["fcn"],
-                                 tile_block=tile_block, interpret=interpret)
-
-    fn = jax.shard_map(local, mesh=mesh,
-                   in_specs=(P(),) + (P(TILE_AXIS),) * 9,
-                   out_specs=P(TILE_AXIS), check_vma=False)
+        tile_block = sharded_tile_block(node_budget, sparse=sparse)
+    local = functools.partial(_packed_kernel, sparse=sparse,
+                              tile_block=tile_block, interpret=interpret)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(), P(TILE_AXIS)),
+                       out_specs=P(TILE_AXIS), check_vma=False)
     return jax.jit(fn), tile_block
-
-
-def build_pair_score_sparse_sharded(mesh: Mesh, node_budget: int, *,
-                                    tile_block: int | None = None,
-                                    interpret: bool | None = None):
-    """Sparse twin of `build_pair_score_packed_sharded`: `fn(params, <17
-    packed-CSR arrays in `pair_score_sparse` order>)`, tile axis sharded."""
-    from repro.distributed.sharding import TILE_AXIS
-
-    if tile_block is None:
-        tile_block = sharded_tile_block(node_budget, sparse=True)
-
-    def local(params, *arrays):
-        return sparse_pair_score(*arrays, params["gcn"], params["att"]["w"],
-                                 params["ntn"], params["fcn"],
-                                 tile_block=tile_block, interpret=interpret)
-
-    fn = jax.shard_map(local, mesh=mesh,
-                   in_specs=(P(),) + (P(TILE_AXIS),) * 17,
-                   out_specs=P(TILE_AXIS), check_vma=False)
-    return jax.jit(fn), tile_block
-
-
-@functools.lru_cache(maxsize=32)
-def _sharded_builder_cached(mesh: Mesh, node_budget: int, sparse: bool,
-                            tile_block: int, interpret: bool | None):
-    build = (build_pair_score_sparse_sharded if sparse
-             else build_pair_score_packed_sharded)
-    return build(mesh, node_budget, tile_block=tile_block,
-                 interpret=interpret)
-
-
-def pair_score_packed_sharded(params, packed, *, mesh: Mesh,
-                              interpret: bool | None = None) -> jax.Array:
-    """Standalone sharded equivalent of `pair_score_packed` (the engine
-    holds its own per-(path, device-count, tile_block) executable cache;
-    this module cache serves tests/benchmarks). Same [T, P] output
-    contract."""
-    t = packed.adj1.shape[0]
-    target, tile_block = sharded_tile_plan(t, packed.node_budget,
-                                           mesh.devices.size)
-    fn, _ = _sharded_builder_cached(mesh, packed.node_budget,
-                                    False, tile_block, interpret)
-    arrays = [_pad_batch(x, target)[0]
-              for x in (packed.adj1, packed.labels1, packed.mask1, packed.seg1,
-                        packed.adj2, packed.labels2, packed.mask2, packed.seg2,
-                        packed.pair_mask)]
-    return fn(params, *arrays)[:t]
-
-
-def pair_score_sparse_sharded(params, packed, *, mesh: Mesh,
-                              interpret: bool | None = None) -> jax.Array:
-    """Standalone sharded equivalent of `pair_score_sparse`."""
-    from repro.core.batching import packed_pair_edges
-
-    edges = packed.edges
-    if edges is None:
-        edges = packed_pair_edges(packed,
-                                  packed_edge_budget(packed.node_budget))
-    t = packed.mask1.shape[0]
-    target, tile_block = sharded_tile_plan(t, packed.node_budget,
-                                           mesh.devices.size, sparse=True)
-    fn, _ = _sharded_builder_cached(mesh, packed.node_budget,
-                                    True, tile_block, interpret)
-    e1, e2 = edges.edges1, edges.edges2
-    o1, o2 = edges.overflow1, edges.overflow2
-    arrays = [_pad_batch(x, target)[0]
-              for x in (e1.senders, e1.weights,
-                        o1.senders, o1.receivers, o1.weights,
-                        packed.labels1, packed.mask1, packed.seg1,
-                        e2.senders, e2.weights,
-                        o2.senders, o2.receivers, o2.weights,
-                        packed.labels2, packed.mask2, packed.seg2,
-                        packed.pair_mask)]
-    return fn(params, *arrays)[:t]

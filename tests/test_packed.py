@@ -8,6 +8,8 @@ the 1e-6 acceptance bound (scores, post-sigmoid); bf16 inputs at the 2e-2
 bound from tests/test_megakernel.py.
 """
 
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,10 +17,13 @@ import pytest
 
 from repro.core.batching import (bucket_for, bucket_pairs, pack_pairs,
                                  pad_graphs, unpack_pair_scores, EdgeBatch)
+from repro.core.engine import ScoringEngine
 from repro.core.simgnn import (SimGNNConfig, init_simgnn_params, pair_score,
                                pair_score_from_labels)
 from repro.data.graphs import random_graph
-from repro.kernels import ops
+from repro.kernels import grad, ops
+from repro.kernels.packed_pair import packed_pair_score
+from repro.kernels.sparse_pair import sparse_pair_score
 from repro.kernels.common import normalize_adjacency_block
 
 CFG = SimGNNConfig()
@@ -138,6 +143,48 @@ def test_packed_variadic_gcn_depth():
         ref[idxs] = np.asarray(pair_score(params, lhs.adj, lhs.feats, lhs.mask,
                                           rhs.adj, rhs.feats, rhs.mask))
     np.testing.assert_allclose(out, ref, rtol=0, atol=1e-6)
+
+
+def _leading_operands(fn, skip: int = 0) -> list[str]:
+    """Names of `fn`'s positional tile operands: those before (and
+    including) `pair_mask`, after `skip` leading non-operand parameters."""
+    names = list(inspect.signature(fn).parameters)[skip:]
+    return names[:names.index("pair_mask") + 1]
+
+
+@pytest.mark.parametrize("sparse", [False, True])
+def test_kernel_operands_match_kernels_and_engine(sparse):
+    """`PackedPairBatch.kernel_operands` is the one statement of the kernel
+    operand order: one operand per leading array parameter of the kernel
+    and of its grad twin, every operand tile-leading, `pair_mask` last; and
+    the ops executor on those operands scores bitwise like the engine's
+    packed executor on the same pool."""
+    path = "packed_sparse" if sparse else "packed_dense"
+    pairs = _mixed_pairs(11, 40, max_n=32)
+    eng = ScoringEngine(PARAMS, CFG, path=path)
+    got = eng.score(pairs)
+    assert eng.last_plan.path == path and not eng.last_plan.degraded_from
+    ps = eng.last_pack_stats
+    packed, _ = pack_pairs(pairs, ps["node_budget"],
+                           slots_per_tile=ps["slots_per_tile"],
+                           with_edges=sparse,
+                           edge_budget=ps.get("edge_budget"),
+                           overflow_budget=ps.get("overflow_budget", 8))
+
+    operands = packed.kernel_operands(sparse)
+    kernel = sparse_pair_score if sparse else packed_pair_score
+    twin = (grad.sparse_pair_score_grad if sparse
+            else grad.packed_pair_score_grad)
+    names = _leading_operands(kernel)
+    assert len(operands) == len(names) == len(_leading_operands(twin, 1))
+    assert names == _leading_operands(twin, 1)
+    assert operands[-1] is packed.pair_mask
+    assert {np.shape(x)[0] for x in operands} == {ps["n_tiles"]}
+
+    score = ops.pair_score_sparse if sparse else ops.pair_score_packed
+    s = score(PARAMS, packed, quantize_tiles=True)
+    np.testing.assert_array_equal(
+        unpack_pair_scores(s, packed, len(pairs)), np.asarray(got))
 
 
 # ------------------------------------------- first-layer one-hot elimination

@@ -24,7 +24,7 @@ from repro.core.engine import ScoringEngine
 from repro.core.profile import TraceRecorder, cost_key
 from repro.core.simgnn import SimGNNConfig, init_simgnn_params
 from repro.data.graphs import random_graph
-from repro.distributed.sharding import TILE_AXIS, tile_mesh, tile_runtime
+from repro.distributed.sharding import tile_runtime
 from repro.kernels import ops
 from repro.serve.search import SimilaritySearchServer
 from repro.testing import faults
@@ -109,22 +109,6 @@ def test_score_parity_bitwise(path, nd):
         assert len(ps["device_occupancy"]) == nd
         assert 0.0 < sum(ps["device_occupancy"]) / nd <= 1.0
         assert eng.last_plan.devices == nd
-
-
-@pytest.mark.parametrize("nd", [2, 8])
-def test_standalone_wrapper_parity_bitwise(nd):
-    if NDEV < nd:
-        pytest.skip(f"needs {nd} host devices")
-    nb = ops.packed_node_budget(CFG.max_nodes)
-    packed, _ = pack_pairs(PAIRS, nb, slots_per_tile=max(8, nb // 4),
-                           with_edges=True)
-    mesh = tile_mesh(nd)
-    ref = ops.pair_score_packed(PARAMS, packed)
-    got = ops.pair_score_packed_sharded(PARAMS, packed, mesh=mesh)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-    ref = ops.pair_score_sparse(PARAMS, packed)
-    got = ops.pair_score_sparse_sharded(PARAMS, packed, mesh=mesh)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 # ---------------------------------------------------- train parity matrix

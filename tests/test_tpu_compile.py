@@ -28,11 +28,7 @@ from repro.core.simgnn import init_simgnn_params
 from repro.data.graphs import query_pairs
 from repro.kernels import ops, retrieval
 from repro.kernels.common import flatten_layer_params
-from repro.kernels.packed_pair import packed_pair_score
-from repro.kernels.sparse_pair import sparse_pair_score
 
-#: the engine's padded tile count for a 256-pair AIDS call (~100 tiles).
-TILES = 128
 CORPUS_ROWS = 8192
 
 
@@ -80,32 +76,15 @@ def aids():
     return params, packed, buckets[max(buckets)], corpus
 
 
-def _tiles(arrays):
-    return [ops._pad_batch(x, TILES)[0] for x in arrays]
+def _packed(sparse):
+    """The engine's single-device packed executor (`ops` body: tile plan,
+    pad, kernel, slice), quantized as the engine calls it."""
+    score = ops.pair_score_sparse if sparse else ops.pair_score_packed
 
-
-def _packed_sparse(params, packed, bucket, corpus):
-    e1, e2 = packed.edges.edges1, packed.edges.edges2
-    o1, o2 = packed.edges.overflow1, packed.edges.overflow2
-    arrays = _tiles((e1.senders, e1.weights, o1.senders, o1.receivers,
-                     o1.weights, packed.labels1, packed.mask1, packed.seg1,
-                     e2.senders, e2.weights, o2.senders, o2.receivers,
-                     o2.weights, packed.labels2, packed.mask2, packed.seg2,
-                     packed.pair_mask))
-    tb = ops.sparse_tile_block(packed.node_budget)
-    return (lambda p, *a: sparse_pair_score(
-        *a, p["gcn"], p["att"]["w"], p["ntn"], p["fcn"], tile_block=tb,
-        interpret=False)), (params, *arrays)
-
-
-def _packed_dense(params, packed, bucket, corpus):
-    arrays = _tiles((packed.adj1, packed.labels1, packed.mask1, packed.seg1,
-                     packed.adj2, packed.labels2, packed.mask2, packed.seg2,
-                     packed.pair_mask))
-    tb = ops.packed_tile_block(packed.node_budget)
-    return (lambda p, *a: packed_pair_score(
-        *a, p["gcn"], p["att"]["w"], p["ntn"], p["fcn"], tile_block=tb,
-        interpret=False)), (params, *arrays)
+    def build(params, packed, bucket, corpus):
+        return (lambda p, pk: score(p, pk, quantize_tiles=True,
+                                    interpret=False)), (params, packed)
+    return build
 
 
 def _bucketed_mega(params, packed, bucket, corpus):
@@ -141,7 +120,7 @@ def _topm_ntn(params, packed, bucket, corpus):
         interpret=False), (uq, dq, corpus, flat))
 
 
-KERNELS = {"packed_sparse": _packed_sparse, "packed_dense": _packed_dense,
+KERNELS = {"packed_sparse": _packed(True), "packed_dense": _packed(False),
            "bucketed_mega": _bucketed_mega, "fused_gcn": _fused_gcn,
            "head": _head, "blocked_topm": _topm,
            "blocked_topm_ntn": _topm_ntn}
