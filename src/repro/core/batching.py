@@ -264,6 +264,64 @@ class PackedEdges(NamedTuple):
         return self.overflow1.senders.shape[-1]
 
 
+def _first_fit(sizes: Sequence[tuple[int, int]], node_budget: int, cap: int):
+    """First-fit-decreasing placement of pairs of node counts `sizes` into
+    tiles of `node_budget` nodes per side and at most `cap` pairs.
+
+    Pairs go in stable order of decreasing n1 + n2, each into the first
+    tile (in creation order) where both sides and a slot still fit. Only
+    *open* tiles are scanned: a tile is closed, for good, once it is full
+    or its free lhs (rhs) budget is below the smallest lhs (rhs) size still
+    to place (suffix minima over the order), since no later pair can enter
+    it. Closing skips only tiles that would fail the check, so the result
+    is the plain FFD scan's.
+
+    Returns per pair its tile and slot, per side the pairs' node offsets in
+    their tile, the tiles' pair counts, and the number of tile checks made.
+    """
+    n = len(sizes)
+    order = sorted(range(n), key=lambda i: -(sizes[i][0] + sizes[i][1]))
+    # low1[k] / low2[k]: smallest lhs / rhs size among order[k:].
+    low1 = [node_budget + 1] * (n + 1)
+    low2 = [node_budget + 1] * (n + 1)
+    for k in range(n - 1, -1, -1):
+        n1, n2 = sizes[order[k]]
+        low1[k] = n1 if n1 < low1[k + 1] else low1[k + 1]
+        low2[k] = n2 if n2 < low2[k + 1] else low2[k + 1]
+    free1: list[int] = []
+    free2: list[int] = []
+    counts: list[int] = []
+    tile_of, slot = [0] * n, [0] * n
+    off1, off2 = [0] * n, [0] * n
+    open_tiles: list[int] = []          # in creation order
+    probes = 0
+    m1 = m2 = 0
+    for k, i in enumerate(order):
+        n1, n2 = sizes[i]
+        if low1[k] != m1 or low2[k] != m2:   # smallest sizes left grew
+            m1, m2 = low1[k], low2[k]
+            open_tiles = [t for t in open_tiles
+                          if free1[t] >= m1 and free2[t] >= m2]
+        for j, t in enumerate(open_tiles):
+            if free1[t] >= n1 and free2[t] >= n2:
+                probes += j + 1
+                break
+        else:
+            probes += len(open_tiles)
+            j, t = len(open_tiles), len(counts)
+            free1.append(node_budget)
+            free2.append(node_budget)
+            counts.append(0)
+            open_tiles.append(t)
+        f1, f2, c = free1[t], free2[t], counts[t]
+        tile_of[i], slot[i] = t, c
+        off1[i], off2[i] = node_budget - f1, node_budget - f2
+        free1[t], free2[t], counts[t] = f1 - n1, f2 - n2, c + 1
+        if c + 1 == cap or f1 - n1 < low1[k + 1] or f2 - n2 < low2[k + 1]:
+            del open_tiles[j]
+    return tile_of, slot, (off1, off2), counts, probes
+
+
 def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
                slots_per_tile: int | None = None,
                with_edges: bool = False, edge_budget: int | None = None,
@@ -291,7 +349,8 @@ def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
     nothing is read back. stats then gains the nnz / adjacency density per
     side, counted on the host, and `degree_rsqrt_misses`.
 
-    stats: occupancy / pad-fraction per side plus tile shape, and the
+    stats: occupancy / pad-fraction per side plus tile shape, the tile
+    checks the first-fit made (`ffd_probes`, see `_first_fit`), and the
     bytes the packing copied to the device (`h2d_bytes`) and back
     (`d2h_bytes`).
     """
@@ -302,24 +361,12 @@ def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
                 f"graph with {max(n1, n2)} nodes exceeds node_budget "
                 f"{node_budget}; route oversized pairs to the padded fallback")
     cap = slots_per_tile if slots_per_tile else len(pairs) or 1
-    order = sorted(range(len(pairs)), key=lambda i: -(sizes[i][0] + sizes[i][1]))
-    tiles: list[dict] = []          # {"used1", "used2", "items": [pair idx]}
-    for i in order:
-        n1, n2 = sizes[i]
-        for t in tiles:
-            if (t["used1"] + n1 <= node_budget
-                    and t["used2"] + n2 <= node_budget
-                    and len(t["items"]) < cap):
-                t["used1"] += n1
-                t["used2"] += n2
-                t["items"].append(i)
-                break
-        else:
-            tiles.append({"used1": n1, "used2": n2, "items": [i]})
+    tile_of, slot, offsets, counts, probes = _first_fit(sizes, node_budget,
+                                                        cap)
 
-    n_tiles = len(tiles) or 1
+    n_tiles = len(counts) or 1
     if slots_per_tile is None:
-        most = max((len(t["items"]) for t in tiles), default=1)
+        most = max(counts, default=1)
         slots_per_tile = max(8, -(-most // 8) * 8)    # sublane-aligned P
     adj = [np.zeros((n_tiles, node_budget, node_budget), np.float32)
            for _ in range(2)]
@@ -328,19 +375,26 @@ def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
     seg = [np.zeros((n_tiles, node_budget), np.int32) for _ in range(2)]
     pair_mask = np.zeros((n_tiles, slots_per_tile), np.float32)
     pair_index = np.zeros((n_tiles, slots_per_tile), np.int32)
-    for t, tile in enumerate(tiles):
-        offs = [0, 0]
-        for p, idx in enumerate(tile["items"]):
-            pair_mask[t, p] = 1.0
-            pair_index[t, p] = idx
-            for side, g in enumerate(pairs[idx]):
-                n = g["adj"].shape[0]
-                o = offs[side]
-                adj[side][t, o:o + n, o:o + n] = g["adj"]
-                labels[side][t, o:o + n] = g["labels"]
-                mask[side][t, o:o + n] = 1.0
-                seg[side][t, o:o + n] = p
-                offs[side] += n
+    tile_np = np.asarray(tile_of, np.int64)
+    slot_np = np.asarray(slot, np.int64)
+    pair_mask[tile_np, slot_np] = 1.0
+    pair_index[tile_np, slot_np] = np.arange(len(pairs))
+    for side in range(2):
+        graphs = [pair[side] for pair in pairs]
+        n = np.asarray([s[side] for s in sizes], np.int64)
+        # Flat node targets, graph by graph in input order: graph i's nodes
+        # go to tile_of[i] * NB + its offset in the tile, onwards.
+        start = tile_np * node_budget + np.asarray(offsets[side], np.int64)
+        flat = (np.repeat(start - (np.cumsum(n) - n), n)
+                + np.arange(int(n.sum())))
+        if graphs:
+            labels[side].reshape(-1)[flat] = np.concatenate(
+                [g["labels"] for g in graphs])
+        mask[side].reshape(-1)[flat] = 1.0
+        seg[side].reshape(-1)[flat] = np.repeat(slot_np, n)
+        a = adj[side]
+        for g, t, o, k in zip(graphs, tile_of, offsets[side], n.tolist()):
+            a[t, o:o + k, o:o + k] = g["adj"]
 
     real = [sum(s[0] for s in sizes), sum(s[1] for s in sizes)]
     cells = max(n_tiles * node_budget, 1)
@@ -351,6 +405,7 @@ def pack_pairs(pairs: Sequence[tuple], node_budget: int = 64, *,
         "pad_fraction_lhs": 1.0 - real[0] / cells,
         "pad_fraction_rhs": 1.0 - real[1] / cells,
         "mean_pairs_per_tile": len(pairs) / n_tiles,
+        "ffd_probes": probes,
         "h2d_bytes": 0, "d2h_bytes": 0,
     }
     if not with_edges:
